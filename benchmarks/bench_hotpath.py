@@ -221,6 +221,9 @@ def bench_e2e(smoke: bool) -> dict:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="CI-sized quick run")
     ap.add_argument(

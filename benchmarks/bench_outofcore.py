@@ -164,6 +164,9 @@ def run(smoke: bool = False, verify_labels: bool | None = None) -> dict:
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--gate", action="store_true",

@@ -161,6 +161,9 @@ def run_outofcore(smoke: bool = True, passes: int = 2) -> dict:
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small out-of-core run; merge into BENCH_hotpath.json")

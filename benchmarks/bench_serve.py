@@ -123,6 +123,9 @@ def run_serve(smoke: bool = True) -> dict:
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run; merge into BENCH_hotpath.json")

@@ -50,11 +50,14 @@ RESTREAM_PASSES = 2
 def run_sharded(smoke: bool = True) -> dict:
     from repro.graphs import DiskNodeStream, rmat_graph, write_packed
     from repro.core import BuffCutConfig, edge_cut, restream_refine
+    from repro.core.multilevel import MultilevelConfig
     from repro.distributed.shard_driver import shard_partition
 
     n = 4096 if smoke else 16384
     io_chunk = 1 << 12
-    cfg = BuffCutConfig(k=8, buffer_size=256, batch_size=128, d_max=256)
+    # the host engine: forked process workers never run jax
+    cfg = BuffCutConfig(k=8, buffer_size=256, batch_size=128, d_max=256,
+                        ml=MultilevelConfig(engine="sparse"))
     out: dict = {
         "cpu_count": int(os.cpu_count() or 1),
         "backend": "process",

@@ -10,6 +10,9 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_ordering, bench_scores, bench_buffer_size, bench_batch_size,
         bench_restream, bench_sota, bench_gnn_comm,

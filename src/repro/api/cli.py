@@ -370,7 +370,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: "list[str] | None" = None) -> int:
+    from repro.compile_cache import enable_compile_cache
+
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     try:
         return args.cmd(args)
     except (ValueError, TypeError, KeyError, FileNotFoundError) as e:

@@ -321,19 +321,27 @@ def multilevel_partition_resilient(
     on_fallback=None,
 ) -> np.ndarray:
     """multilevel_partition with graceful degradation (DESIGN.md §11): a
-    failure inside the jax engine (device OOM, runtime error, backend gone
-    mid-run) re-partitions the batch on the sparse host engine instead of
-    killing an hours-long stream run.  Safe because engine parity is pinned
-    — sparse and jax produce bit-identical labels — so the fallback changes
-    nothing but throughput.  Host-engine failures are real bugs and
-    propagate.  `on_fallback` (if given) is called once per degraded batch
+    device runtime failure inside the jax engine (`JaxRuntimeError`: device
+    OOM, backend gone mid-run) re-partitions the batch on the sparse host
+    engine instead of killing an hours-long stream run.  On integer-weight
+    graphs engine parity is pinned — sparse and jax produce bit-identical
+    labels — so the fallback changes nothing but throughput; on
+    float-weighted graphs the device's capacity acceptance ranks gains and
+    sums target loads in another order and precision than numpy's, and a
+    batch that falls back may take other labels.
+    Everything else
+    propagates: import, lowering and `NotImplementedError` failures are a
+    broken device path, not a lost device, and host-engine failures are
+    real bugs.  `on_fallback` (if given) is called once per degraded batch
     so drivers can count them in `StreamStats.engine_fallbacks`."""
     cfg = cfg or MultilevelConfig()
+    if cfg.engine != "jax":
+        return multilevel_partition(g, pinned, p, loads_base, cfg)
+    import jax
+
     try:
         return multilevel_partition(g, pinned, p, loads_base, cfg)
-    except Exception:
-        if cfg.engine != "jax":
-            raise
+    except jax.errors.JaxRuntimeError:
         if on_fallback is not None:
             on_fallback()
         host_cfg = dataclasses.replace(cfg, engine="sparse")

@@ -30,7 +30,7 @@ padded volume:
 
 Labels live on device across all levels and transfer to host exactly once,
 when the committed batch's assignment is read back.  All arithmetic runs
-under `jax.experimental.enable_x64` so results are *identical* to the
+under `jax.enable_x64(True)` so results are *identical* to the
 numpy `sparse` oracle at fixed seed (integer-weight graphs; pinned by
 tests/test_multilevel_jax.py).  Host-side work is limited to per-level
 scalar pulls (free-node count, coarse size) that drive the level loop.
@@ -43,7 +43,6 @@ import time
 import jax  # repro: noqa RPR001 -- whole-module jax engine; imported lazily by core/multilevel.py
 import jax.numpy as jnp  # repro: noqa RPR001 -- jax engine module
 import numpy as np
-from jax.experimental import enable_x64  # repro: noqa RPR001 -- jax engine module
 
 from repro.core.fennel import FennelParams
 from repro.core.multilevel import _ELL_VOLUME_CAP as ELL_VOLUME_CAP
@@ -154,6 +153,82 @@ def reset_agg_tuner() -> None:
 
 
 # --------------------------------------------------------------------------
+# TPU-compilable building blocks
+#
+# The TPU has no 64-bit ALU: under x64 its compiler emulates every f64/i64
+# op, and it takes minutes over a sort with 64-bit or multi-word keys, a
+# float64 cumsum, or a flat scan over 2^16+ elements.  So indices are int32
+# throughout, every large sort has a single u32 key (up to n_pad = 2^16),
+# prefix counts are matmuls, and per-group reductions are scatters.
+# Weights, loads and gains stay f64 (ROADMAP §1.4 owns that question).
+# --------------------------------------------------------------------------
+
+_I32 = jnp.int32
+_U32 = jnp.uint32
+_SCAN_BLOCK = 128
+
+
+def _prefix_count(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of a non-negative int32 vector whose total is
+    below 2^24 (0/1 flags here), as a matmul against a triangular block.
+
+    The TPU compiler takes minutes over a flat cumsum or associative scan
+    of 2^20 elements, and seconds over this; the f32 sums are exact below
+    2^24."""
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK or n % _SCAN_BLOCK:
+        return jnp.cumsum(x, dtype=_I32)
+    tri = jnp.triu(jnp.ones((_SCAN_BLOCK, _SCAN_BLOCK), jnp.float32))
+    rows = jnp.dot(x.reshape(-1, _SCAN_BLOCK).astype(jnp.float32), tri,
+                   precision=jax.lax.Precision.HIGHEST).astype(_I32)
+    totals = rows[:, -1]
+    carry = _prefix_count(totals) - totals
+    return (rows + carry[:, None]).reshape(n)
+
+
+def _pair_key(a: jnp.ndarray, b: jnp.ndarray, base: int,
+              valid: jnp.ndarray) -> jnp.ndarray:
+    """Unsigned key a·base + b for a, b < base; the dtype's max (the
+    sentinel, sorting behind every valid key) where invalid.  u32 up to
+    base = 2^16, u64 beyond (a slow sort to compile on the TPU).
+
+    At base = 2^16 the valid key (base-1, base-1) equals the sentinel; both
+    callers make that harmless (contract drops self loops, aggregation
+    entries behind the sentinel carry zero weight)."""
+    dt = _U32 if base <= 1 << 16 else jnp.uint64
+    key = a.astype(dt) * dt(base) + b.astype(dt)
+    return jnp.where(valid, key, _sentinel(key))
+
+
+def _sentinel(key: jnp.ndarray):
+    return key.dtype.type(jnp.iinfo(key.dtype).max)
+
+
+def _sort_by_key(key: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(sorted key, permutation) from one single-key sort."""
+    iota = jnp.arange(key.shape[0], dtype=_I32)
+    key_s, order = jax.lax.sort((key, iota), num_keys=1, is_stable=False)
+    return key_s, order
+
+
+def _f32_key(x: jnp.ndarray) -> jnp.ndarray:
+    """u32 whose unsigned order is the float order of f32 `x` (-0.0 == 0.0)."""
+    b = jax.lax.bitcast_convert_type(jnp.where(x == 0, jnp.float32(0), x),
+                                     _U32)
+    return jnp.where((b >> 31) == 1, ~b, b | _U32(0x80000000))
+
+
+def _desc_words(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Two u32 words whose lexicographic order is the descending order of
+    f64 `x`: x = hi + lo with hi, lo f32.  Rounding is monotone, so the
+    order is kept exactly for values with <= 48 significant bits — every
+    integer-weight graph, which is what the parity contract covers."""
+    hi = x.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(x), x - hi.astype(x.dtype), 0.0)
+    return ~_f32_key(hi), ~_f32_key(lo.astype(jnp.float32))
+
+
+# --------------------------------------------------------------------------
 # aggregation: per-node (cur_conn, best_w, best_lab) from neighbor labels
 # --------------------------------------------------------------------------
 
@@ -175,15 +250,16 @@ def _best_from_counts(
     raw ascending labels, so ties break toward the lower label).
     """
     rows_n, l_pad = counts.shape
-    rows = jnp.arange(rows_n)
+    rows = jnp.arange(rows_n, dtype=_I32)
     own_c = jnp.clip(own, 0, l_pad - 1)
     cur_conn = jnp.where(own >= 0, counts[rows, own_c], 0.0)
     if forbidden_cols is not None:
         counts = jnp.where(forbidden_cols[None, :], -jnp.inf, counts)
-    col_ids = jnp.arange(l_pad)
+    col_ids = jnp.arange(l_pad, dtype=_I32)
     counts = jnp.where(col_ids[None, :] == own[:, None], -jnp.inf, counts)
-    best_col = jnp.argmax(counts, axis=1)
-    best_w = counts[rows, best_col]
+    best_w = jnp.max(counts, axis=1)
+    # first column holding the row max (a bool argmax: no f64 comparator)
+    best_col = jnp.argmax(counts == best_w[:, None], axis=1).astype(_I32)
     return cur_conn, best_w, best_col
 
 
@@ -222,83 +298,72 @@ def _agg_ell(
 
     mask = nbr >= 0
     lab = jnp.where(mask, labels[jnp.clip(nbr, 0, n_pad - 1)], -1)
+    # kernels on means compiled: never the interpreter on a device
+    on = _ops.USE_KERNELS_DEFAULT
     counts = _ops.block_histogram(
         lab.astype(jnp.int32), wts.astype(jnp.float32), l_pad,
-        use_kernel=_ops.USE_KERNELS_DEFAULT,
+        use_kernel=on, interpret=not on,
     )
     # f32 kernel accumulator -> f64 epilogue, same cast the host ELL engine
     # performs (exact for the integer-weight graphs the parity suite pins)
     return _best_from_counts(counts.astype(jnp.float64), own, forbidden_cols)
 
 
-def _agg_sort(
-    esrc: jnp.ndarray,
-    edst: jnp.ndarray,
-    ew: jnp.ndarray,
-    labels: jnp.ndarray,
-    own: jnp.ndarray,
-    forbidden: jnp.ndarray | None,
-    n_pad: int,
+def _per_node_best(src, lab, total, own, live, elig, n_pad):
+    """Per-node (cur_conn, best_w, best_lab) from (src, label, total) group
+    rows: `live` rows count, `elig` rows may be move targets.  Scatter
+    max/min, so the result is exact in any order."""
+    seg = jnp.where(live, src, n_pad)
+    is_own = live & (lab == own[jnp.clip(src, 0, n_pad - 1)])
+    elig = elig & ~is_own
+
+    def seg_max(v):
+        return jax.ops.segment_max(v, seg, num_segments=n_pad + 1)[:n_pad]
+
+    cur_conn = seg_max(jnp.where(is_own, total, -jnp.inf))
+    cur_conn = jnp.where(jnp.isfinite(cur_conn), cur_conn, 0.0)
+    best_w = seg_max(jnp.where(elig, total, -jnp.inf))
+    is_best = elig & (total == best_w[jnp.clip(src, 0, n_pad - 1)])
+    best_lab = jax.ops.segment_min(jnp.where(is_best, lab, n_pad), seg,
+                                   num_segments=n_pad + 1)[:n_pad]
+    return cur_conn, best_w, jnp.minimum(best_lab, n_pad)
+
+
+def _agg_key(esrc, edst, labels, n_pad: int) -> jnp.ndarray:
+    """u32 (src, label) sort key of every directed edge (sentinel if none)."""
+    lab = _edge_labels(edst, labels, n_pad)
+    return _pair_key(esrc, lab, n_pad, (esrc < n_pad) & (lab >= 0))
+
+
+def _agg_presorted(
+    esrc, edst, ew, labels, own, forbidden, n_pad: int, key_s, order,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Segmented-sort aggregation: no dense scratch, any label domain."""
+    """Sort aggregation after the (src, label) sort: each group's total
+    lands at its first position by a sorted segment-sum over binary-searched
+    group starts (no scan), then scatter max/min per node."""
     lab = _edge_labels(edst, labels, n_pad)
     valid = (esrc < n_pad) & (lab >= 0)
-    base = jnp.int64(n_pad + 1)
-    key = jnp.where(valid, esrc * base + lab, base * base - 1)
-    order = jnp.argsort(key, stable=True)
-    key_s, w_s = key[order], ew[order]
-    src_s = jnp.minimum(key_s // base, n_pad)
-    lab_s = key_s % base
-    # per-(src, label) group totals via a restarted cumsum, read at group
-    # end positions — everything below is scans and gathers, no scatters
-    gstart = jnp.concatenate([jnp.ones(1, bool), key_s[1:] != key_s[:-1]])
-    gend = jnp.concatenate([key_s[1:] != key_s[:-1], jnp.ones(1, bool)])
-    csum = jnp.cumsum(w_s)
-    gbase = jax.lax.cummax(jnp.where(gstart, csum - w_s, -jnp.inf))
-    total = csum - gbase
-    # zero-sum groups dropped to match aggregate_by_key's dense path
-    live = gend & (src_s < n_pad) & (total != 0)
-    own_s = own[jnp.minimum(src_s, n_pad - 1)]
-    is_own = live & (lab_s == own_s)
-    elig = live & ~is_own
+    w_s = jnp.where(valid, ew, 0.0)[order]
+    first = jnp.searchsorted(key_s, key_s, side="left").astype(_I32)
+    total = jax.ops.segment_sum(w_s, first, num_segments=key_s.shape[0],
+                                indices_are_sorted=True)
+    base = key_s.dtype.type(n_pad)
+    src_s = (key_s // base).astype(_I32)
+    lab_s = (key_s % base).astype(_I32)
+    # zero-sum groups dropped to match aggregate_by_key's dense path; the
+    # sentinel group (all zero weight) goes with them
+    live = (first == jnp.arange(key_s.shape[0], dtype=_I32)) & (total != 0)
+    elig = live
     if forbidden is not None:
         elig &= ~forbidden[jnp.clip(lab_s, 0, n_pad - 1)]
-    nstart = jnp.concatenate([jnp.ones(1, bool), src_s[1:] != src_s[:-1]])
-    own_run = _seg_scan(jnp.where(is_own, total, -jnp.inf), nstart,
-                        jnp.maximum)  # <=1 own group per node: max picks it
-    cur_conn = _ends_gather(src_s, own_run, n_pad, -jnp.inf)
-    cur_conn = jnp.where(jnp.isfinite(cur_conn), cur_conn, 0.0)
-    best_run = _seg_scan(jnp.where(elig, total, -jnp.inf), nstart,
-                         jnp.maximum)
-    best_w = _ends_gather(src_s, best_run, n_pad, -jnp.inf)
-    is_best = elig & (total == best_w[jnp.minimum(src_s, n_pad - 1)])
-    lab_run = _seg_scan(jnp.where(is_best, lab_s, base), nstart, jnp.minimum)
-    best_lab = _ends_gather(src_s, lab_run, n_pad, base)
-    return cur_conn, best_w, best_lab
+    return _per_node_best(src_s, lab_s, total, own, live, elig, n_pad)
 
 
-def _seg_scan(val: jnp.ndarray, start: jnp.ndarray, op) -> jnp.ndarray:
-    """Segmented inclusive scan (op = jnp.maximum / jnp.minimum): the scan
-    restarts wherever `start` is True.  Scatter-free — on CPU this is the
-    fast replacement for jax.ops.segment_* over presorted segments."""
-    def comb(a, b):
-        fa, va = a
-        fb, vb = b
-        return fa | fb, jnp.where(fb, vb, op(va, vb))
-
-    _, out = jax.lax.associative_scan(comb, (start, val))
-    return out
-
-
-def _ends_gather(src_s: jnp.ndarray, run: jnp.ndarray, n_pad: int,
-                 fill) -> jnp.ndarray:
-    """Per-node value from a segmented running reduction: node v's result
-    sits at the last position of its (contiguous) run in the sorted src
-    column; nodes without entries get `fill`."""
-    pos = jnp.searchsorted(src_s, jnp.arange(n_pad), side="right") - 1
-    pos_c = jnp.maximum(pos, 0)
-    hit = (pos >= 0) & (src_s[pos_c] == jnp.arange(n_pad))
-    return jnp.where(hit, run[pos_c], fill)
+def _agg_sort(esrc, edst, ew, labels, own, forbidden, n_pad: int):
+    """Sort aggregation: no dense scratch, any label domain."""
+    key_s, order = _sort_by_key(_agg_key(esrc, edst, labels, n_pad))
+    return _agg_presorted(esrc, edst, ew, labels, own, forbidden, n_pad,
+                          key_s, order)
 
 
 def _agg_round0(
@@ -312,20 +377,18 @@ def _agg_round0(
     the CSR *is* the histogram — per-node max edge weight, tie toward the
     lower neighbor id, no sort and no dense scratch.  The device twin of
     neighbor_label_weights' L == n fast path (zero-weight edges dropped
-    the same way).  Edges are src-sorted, so the per-node reductions are
-    segmented scans read off at segment ends."""
-    valid = (esrc < n_pad) & (ew != 0)
+    the same way)."""
+    valid = (esrc < n_pad) & (edst < n_pad) & (ew != 0)
     elig = valid & ~forbidden[jnp.minimum(edst, n_pad - 1)]
-    nstart = jnp.concatenate([jnp.ones(1, bool), esrc[1:] != esrc[:-1]])
-    w_elig = jnp.where(elig, ew, -jnp.inf)
-    best_run = _seg_scan(w_elig, nstart, jnp.maximum)
-    best_w = _ends_gather(esrc, best_run, n_pad, -jnp.inf)
+    seg = jnp.where(elig, esrc, n_pad)
+    best_w = jax.ops.segment_max(jnp.where(elig, ew, -jnp.inf), seg,
+                                 num_segments=n_pad + 1)[:n_pad]
     is_best = elig & (ew == best_w[jnp.minimum(esrc, n_pad - 1)])
-    lab_cand = jnp.where(is_best, edst, n_pad)
-    lab_run = _seg_scan(lab_cand, nstart, jnp.minimum)
-    best_lab = _ends_gather(esrc, lab_run, n_pad, n_pad)
+    best_lab = jax.ops.segment_min(jnp.where(is_best, edst, n_pad), seg,
+                                   num_segments=n_pad + 1)[:n_pad]
     # no self loops -> own-label connectivity is identically zero
-    return jnp.zeros(n_pad, dtype=ew.dtype), best_w, best_lab
+    return (jnp.zeros(n_pad, dtype=ew.dtype), best_w,
+            jnp.minimum(best_lab, n_pad))
 
 
 def _aggregate(
@@ -335,15 +398,12 @@ def _aggregate(
     """Dispatch one of the three modes; `forbidden` is a label-domain mask
     (length l_pad for dense/ell, node-domain length n_pad for sort)."""
     if mode == "dense":
-        cur, bw, bl = _agg_dense(esrc, edst, ew, labels, own, forbidden,
-                                 n_pad, l_pad)
-    elif mode == "ell":
-        cur, bw, bl = _agg_ell(nbr, wts, labels, own, forbidden, n_pad, l_pad)
-    elif mode == "sort":
+        return _agg_dense(esrc, edst, ew, labels, own, forbidden, n_pad, l_pad)
+    if mode == "ell":
+        return _agg_ell(nbr, wts, labels, own, forbidden, n_pad, l_pad)
+    if mode == "sort":
         return _agg_sort(esrc, edst, ew, labels, own, forbidden, n_pad)
-    else:  # pragma: no cover - host picks from a closed set
-        raise ValueError(f"unknown aggregation mode {mode!r}")
-    return cur, bw, bl
+    raise ValueError(f"unknown aggregation mode {mode!r}")  # pragma: no cover
 
 
 # --------------------------------------------------------------------------
@@ -358,129 +418,188 @@ def _accept_with_capacity(
     capacity: jnp.ndarray,
     n_pad: int,
 ) -> jnp.ndarray:
-    """Per-target gain-descending prefix acceptance, on device.
+    """Per-target gain-descending prefix acceptance, on device, sort-free.
 
-    Non-movers sort behind every real target (sentinel target = n_pad) and
-    carry zero weight, so the per-group cumulative sums are float-identical
-    to the numpy compacted formulation (adding 0.0 is exact).
+    The numpy engine orders movers by (target, gain desc, id asc) and takes
+    each while its target's running weight, itself included, fits the
+    capacity.  That running weight only grows along the order, so target t
+    accepts exactly the movers whose rank key (gain desc, id asc) is at most
+    θ_t, the largest key with F_t(θ_t) = w{movers of t keyed <= θ_t} <=
+    cap_t.  θ_t is built bit by bit, most significant first, with one
+    segment-sum per bit.  The sums are exact for integer weights, so the
+    accepted set is the numpy one wherever the parity contract applies.
     """
-    tgt = jnp.where(movers, targets, n_pad)
-    gn = jnp.where(movers, gains, 0.0)
-    order = jnp.lexsort((-gn, tgt))  # target, then gain desc; stable -> id asc
-    t_s = tgt[order]
-    m_s = movers[order]
-    w_s = jnp.where(m_s, node_w[order], 0.0)
-    csum = jnp.cumsum(w_s)
-    seg_start = jnp.concatenate([jnp.ones(1, bool), t_s[1:] != t_s[:-1]])
-    base = jnp.where(seg_start, csum - w_s, -jnp.inf)
-    within = csum - jax.lax.cummax(base)  # cumsum restarted per target group
-    cap_t = jnp.where(t_s >= n_pad, 0.0, capacity[jnp.clip(t_s, 0, n_pad - 1)])
-    ok = m_s & (within <= cap_t + 1e-9)
-    return jnp.zeros(n_pad, dtype=bool).at[order].set(ok)
+    tgt = jnp.where(movers, targets, n_pad).astype(_I32)
+    w = jnp.where(movers, node_w, 0.0)
+    hi, lo = _desc_words(jnp.where(movers, gains, 0.0))
+    words = (hi, lo, jnp.arange(n_pad, dtype=_U32))
+    cap = jnp.concatenate([capacity[:n_pad], jnp.zeros(1, capacity.dtype)])
+    cap = cap + 1e-9
+
+    def weight_upto(theta):
+        t = [th[tgt] for th in theta]
+        le = (words[0] < t[0]) | ((words[0] == t[0]) & (
+            (words[1] < t[1]) | ((words[1] == t[1]) & (words[2] <= t[2]))))
+        f = jax.ops.segment_sum(jnp.where(le, w, 0.0), tgt,
+                                num_segments=n_pad + 1)
+        return le, f
+
+    id_bits = max(n_pad - 1, 1).bit_length()
+    sched = [(0, b) for b in range(31, -1, -1)] + \
+            [(1, b) for b in range(31, -1, -1)] + \
+            [(2, b) for b in range(id_bits - 1, -1, -1)]
+    s_word = jnp.asarray([s[0] for s in sched], dtype=_I32)
+    s_bit = jnp.asarray([1 << s[1] for s in sched], dtype=_U32)
+
+    def step(i, theta):
+        word, bit = s_word[i], s_bit[i]
+        cand = tuple(jnp.where(word == j, th | bit, th)
+                     for j, th in enumerate(theta))
+        ok = weight_upto(cand)[1] <= cap
+        return tuple(jnp.where(ok, c, th) for c, th in zip(cand, theta))
+
+    zero = jnp.zeros(n_pad + 1, dtype=_U32)
+    theta = jax.lax.fori_loop(0, len(sched), step, (zero, zero, zero))
+    le, f = weight_upto(theta)
+    return movers & le & (f[tgt] <= cap[tgt])
 
 
 # --------------------------------------------------------------------------
 # jitted V-cycle stages
 # --------------------------------------------------------------------------
 
-def _lp_cluster(esrc, edst, ew, nbr, wts, node_w, pinned, n, max_cluster_w,
-                *, iters: int, mode: str):
-    """Size-constrained LP clustering; returns the cluster label vector."""
-    _count_trace("lp_cluster")
+# Every sort of more than a few thousand keys takes the TPU compiler 10-20 s
+# whatever else its program holds, and the level shapes of a stream vary
+# (pow2 buckets of noisy edge counts).  So the big sorts run in one shared
+# program keyed by length alone, `_sort_j`, and the stages around them
+# compile in a second or two per shape.
+
+def _sort(key):
+    _count_trace("sort")
+    return _sort_by_key(key)
+
+
+def _cluster_key(esrc, edst, cluster):
+    """Sort key of a clustering round's aggregation (labels = clusters)."""
+    _count_trace("cluster_key")
+    return _agg_key(esrc, edst, cluster, cluster.shape[0])
+
+
+def _lp_cluster_round(esrc, edst, ew, nbr, wts, node_w, pinned, n,
+                      max_cluster_w, cluster, cw, key_s, order, *, mode: str):
+    """One size-constrained LP clustering round.  `cluster` None is round
+    0: labels are all distinct, and the sort-free all-distinct fast path
+    aggregates; later rounds use `mode` (sort: over the presorted keys)."""
+    _count_trace("lp_cluster" if cluster is not None else "lp_cluster0")
     n_pad = node_w.shape[0]
-    valid = jnp.arange(n_pad) < n
+    valid = jnp.arange(n_pad, dtype=_I32) < n
     free = (pinned == -1) & valid
     # pinned-owned clusters are never targets; cluster labels are node ids,
     # so the node-domain mask doubles as the label-column mask (l_pad = n_pad)
     forbidden = pinned >= 0
-    cluster = jnp.arange(n_pad)
-    cw = jnp.where(valid, node_w, 0.0)
-
-    # rounds unroll in Python (iters is static): round 0 always hits the
-    # sort-free all-distinct fast path, later rounds use `mode`
-    for round_idx in range(iters):
-        if round_idx == 0:
-            _, best_w, best_lab = _agg_round0(esrc, edst, ew, forbidden,
-                                              n_pad)
-        else:
-            _, best_w, best_lab = _aggregate(
-                mode, esrc, edst, ew, nbr, wts, cluster, cluster, forbidden,
-                n_pad, n_pad)
-        movers = free & (best_w > 0.0)
-        tgt_c = jnp.clip(best_lab, 0, n_pad - 1)
-        movers &= cw[tgt_c] + node_w <= max_cluster_w
-        capacity = jnp.maximum(max_cluster_w - cw, 0.0)
-        accept = _accept_with_capacity(movers, best_lab, best_w, node_w,
-                                       capacity, n_pad)
-        wmv = jnp.where(accept, node_w, 0.0)
-        out = jnp.where(accept, best_lab, n_pad)
-        src_c = jnp.where(accept, cluster, n_pad)
-        cw = (cw
-              - jax.ops.segment_sum(wmv, src_c, num_segments=n_pad + 1)[:n_pad]
-              + jax.ops.segment_sum(wmv, out, num_segments=n_pad + 1)[:n_pad])
-        cluster = jnp.where(accept, best_lab, cluster)
-    return cluster
+    if cluster is None:
+        cluster = jnp.arange(n_pad, dtype=_I32)
+        cw = jnp.where(valid, node_w, 0.0)
+        _, best_w, best_lab = _agg_round0(esrc, edst, ew, forbidden, n_pad)
+    elif mode == "sort":
+        _, best_w, best_lab = _agg_presorted(
+            esrc, edst, ew, cluster, cluster, forbidden, n_pad, key_s, order)
+    else:
+        _, best_w, best_lab = _aggregate(
+            mode, esrc, edst, ew, nbr, wts, cluster, cluster, forbidden,
+            n_pad, n_pad)
+    movers = free & (best_w > 0.0)
+    tgt_c = jnp.clip(best_lab, 0, n_pad - 1)
+    movers &= cw[tgt_c] + node_w <= max_cluster_w
+    capacity = jnp.maximum(max_cluster_w - cw, 0.0)
+    accept = _accept_with_capacity(movers, best_lab, best_w, node_w,
+                                   capacity, n_pad)
+    wmv = jnp.where(accept, node_w, 0.0)
+    out = jnp.where(accept, best_lab, n_pad)
+    src_c = jnp.where(accept, cluster, n_pad)
+    cw = (cw
+          - jax.ops.segment_sum(wmv, src_c, num_segments=n_pad + 1)[:n_pad]
+          + jax.ops.segment_sum(wmv, out, num_segments=n_pad + 1)[:n_pad])
+    return jnp.where(accept, best_lab, cluster), cw
 
 
-def _contract(esrc, edst, ew, cluster, node_w, pinned, n):
-    """Cluster contraction into the same padded buffers.
+def _contract_nodes(esrc, edst, cluster, node_w, pinned, n):
+    """Cluster contraction, node side, plus the coarse-edge sort key.
 
     Coarse ids are the ascending ranks of the surviving cluster ids (the
-    device twin of np.unique(..., return_inverse=True)); coarse edges are
-    one segment-sum over composite keys.  Returns the coarse graph arrays,
-    the fine->coarse node map and the coarse node count.
+    device twin of np.unique(..., return_inverse=True)): a presence bitmap
+    and its prefix sum, since cluster ids are node ids.  Returns the coarse
+    node weights and pins, the fine->coarse node map, the coarse node count
+    and the u32 (coarse-src, coarse-dst) key of every fine edge.
     """
     _count_trace("contract")
     n_pad = node_w.shape[0]
-    e_pad = esrc.shape[0]
-    valid = jnp.arange(n_pad) < n
+    valid = jnp.arange(n_pad, dtype=_I32) < n
     cl = jnp.where(valid, cluster, n_pad)
-    sorted_cl = jnp.sort(cl)
-    is_first = jnp.concatenate(
-        [jnp.ones(1, bool), sorted_cl[1:] != sorted_cl[:-1]])
-    is_first &= sorted_cl < n_pad
-    rank = jnp.cumsum(is_first) - 1
-    nc = jnp.sum(is_first)
-    value_rank = jnp.zeros(n_pad + 1, dtype=cl.dtype).at[sorted_cl].set(rank)
-    node_map = jnp.where(valid, value_rank[jnp.minimum(cl, n_pad)], n_pad)
+    present = jnp.zeros(n_pad + 1, dtype=_I32).at[cl].set(1)[:n_pad]
+    rank = _prefix_count(present)
+    nc = rank[-1]
+    node_map = jnp.where(valid, rank[jnp.minimum(cl, n_pad - 1)] - 1, n_pad)
 
-    cvalid = jnp.arange(n_pad) < nc
+    cvalid = jnp.arange(n_pad, dtype=_I32) < nc
     cw = jax.ops.segment_sum(
         jnp.where(valid, node_w, 0.0),
         jnp.where(valid, node_map, n_pad), num_segments=n_pad + 1)[:n_pad]
     pin_idx = jnp.where(valid & (pinned >= 0), node_map, n_pad)
-    cpin = jnp.full(n_pad + 1, jnp.int64(-1)).at[pin_idx].max(
+    cpin = jnp.full(n_pad + 1, -1, dtype=_I32).at[pin_idx].max(
         jnp.where(valid, pinned, -1))[:n_pad]
     cpin = jnp.where(cvalid, cpin, -2)
 
-    epad = esrc >= n_pad
+    epad = (esrc >= n_pad) | (edst >= n_pad)
     s2 = jnp.where(epad, n_pad, node_map[jnp.minimum(esrc, n_pad - 1)])
     d2 = jnp.where(epad, n_pad, node_map[jnp.minimum(edst, n_pad - 1)])
-    base = jnp.int64(n_pad + 1)
-    drop = epad | (s2 == d2)
-    key = jnp.where(drop, base * base - 1, s2 * base + d2)
-    order = jnp.argsort(key, stable=True)
-    key_s, w_s = key[order], ew[order]
+    # self loops dropped, so the key (n_pad-1, n_pad-1) that shares the
+    # sentinel's bits at n_pad = 2^16 never occurs
+    key = _pair_key(s2, d2, n_pad, ~epad & (s2 != d2))
+    return cw, cpin, node_map, nc, key
+
+
+def _contract_edges(ew, key_s, order, n_pad: int):
+    """Cluster contraction, edge side: one segment-sum over the sorted
+    (coarse-src, coarse-dst) keys, front-compacted into the same padded
+    buffers.  Returns the coarse edge arrays and their count."""
+    _count_trace("contract_edges")
+    e_pad = key_s.shape[0]
+    w_s = ew[order]
     seg_start = jnp.concatenate([jnp.ones(1, bool), key_s[1:] != key_s[:-1]])
-    gid = jnp.cumsum(seg_start) - 1
+    gid = _prefix_count(seg_start.astype(_I32)) - 1
     sums = jax.ops.segment_sum(w_s, gid, num_segments=e_pad,
                                indices_are_sorted=True)
     gkey = jax.ops.segment_max(key_s, gid, num_segments=e_pad,
                                indices_are_sorted=True)
     n_groups = gid[-1] + 1
-    gsrc = gkey // base
     # zero-sum groups are kept as zero-weight edges (every consumer ignores
     # them) so the coarse arrays stay src-sorted — _initial_fennel slices
     # per-node segments out of them by searchsorted
-    valid_g = (jnp.arange(e_pad) < n_groups) & (gsrc < n_pad)
-    esrc2 = jnp.where(valid_g, gsrc, n_pad)
-    edst2 = jnp.where(valid_g, gkey % base, n_pad)
+    valid_g = ((jnp.arange(e_pad, dtype=_I32) < n_groups)
+               & (gkey != _sentinel(gkey)))
+    base = gkey.dtype.type(n_pad)
+    esrc2 = jnp.where(valid_g, (gkey // base).astype(_I32), n_pad)
+    edst2 = jnp.where(valid_g, (gkey % base).astype(_I32), n_pad)
     ew2 = jnp.where(valid_g, sums, 0.0)
-    ne = jnp.sum(valid_g)
-    return esrc2, edst2, ew2, cw, cpin, node_map, nc, ne
+    return esrc2, edst2, ew2, jnp.sum(valid_g)
 
 
-def _initial_fennel(esrc, edst, ew, node_w, pinned, n, loads0,
+def _fennel_order(node_w, pinned, n):
+    """Initial-partition visit order: free nodes by weight desc, ties id
+    asc.  The numpy oracle orders coarse nodes by their float32 weights
+    (its contraction stores them as f32), so one u32 word of the f32
+    weight is the whole key: a stable single-key sort, in a program of its
+    own so that it compiles once per node bucket, not per edge shape."""
+    _count_trace("fennel_order")
+    n_pad = node_w.shape[0]
+    free = (pinned == -1) & (jnp.arange(n_pad, dtype=_I32) < n)
+    key = ~_f32_key(jnp.where(free, node_w, -jnp.inf).astype(jnp.float32))
+    return jax.lax.sort((key, jnp.arange(n_pad, dtype=_I32)), num_keys=1,
+                        is_stable=True)[1]
+
+
+def _initial_fennel(esrc, edst, ew, node_w, pinned, n, order, loads0,
                     alpha, gamma, cap, *, w_c: int):
     """Weighted Fennel on the coarsest level, heaviest free nodes first.
 
@@ -494,15 +613,13 @@ def _initial_fennel(esrc, edst, ew, node_w, pinned, n, loads0,
     _count_trace("initial_fennel")
     n_pad = node_w.shape[0]
     k = loads0.shape[0]
-    valid = jnp.arange(n_pad) < n
+    valid = jnp.arange(n_pad, dtype=_I32) < n
     free = (pinned == -1) & valid
-    wkey = jnp.where(free, node_w, -jnp.inf)
-    order = jnp.argsort(-wkey, stable=True)  # weight desc, ties id asc
     n_free = jnp.sum(free)
     labels0 = jnp.where(valid & (pinned >= 0), pinned, -1)
     # per-node segment starts in the sorted edge arrays
-    indptr = jnp.searchsorted(esrc, jnp.arange(n_pad))
-    blk_ids = jnp.arange(k)
+    indptr = jnp.searchsorted(esrc, jnp.arange(n_pad, dtype=_I32)).astype(_I32)
+    blk_ids = jnp.arange(k, dtype=_I32)
 
     def step(i, carry):
         labels, loads = carry
@@ -521,9 +638,10 @@ def _initial_fennel(esrc, edst, ew, node_w, pinned, n, loads0,
         score = conn - penalty
         nw = node_w[v]
         feasible = loads + nw <= cap
-        blk = jnp.where(feasible.any(),
-                        jnp.argmax(jnp.where(feasible, score, -jnp.inf)),
-                        jnp.argmin(loads))
+        masked = jnp.where(feasible, score, -jnp.inf)
+        best = jnp.argmax(masked == jnp.max(masked)).astype(_I32)
+        lightest = jnp.argmax(loads == jnp.min(loads)).astype(_I32)
+        blk = jnp.where(feasible.any(), best, lightest)
         labels = labels.at[v].set(blk)
         loads = loads + nw * (blk_ids == blk)
         return labels, loads
@@ -537,7 +655,7 @@ def _lp_refine(esrc, edst, ew, nbr, wts, node_w, pinned, n, labels, loads,
     _count_trace("lp_refine")
     n_pad = node_w.shape[0]
     k = loads.shape[0]
-    valid = jnp.arange(n_pad) < n
+    valid = jnp.arange(n_pad, dtype=_I32) < n
     free = (pinned == -1) & valid
 
     def round_(_, state):
@@ -570,9 +688,13 @@ def _project(labels, node_map, pinned):
     return jnp.where(pinned >= 0, pinned, jnp.where(node_map < n_pad, fine, -1))
 
 
-_lp_cluster_j = _jit(_lp_cluster, static=("iters", "mode"))
-_contract_j = _jit(_contract)
-_initial_fennel_j = _jit(_initial_fennel, static=("w_c",), donate=(6,))
+_sort_j = _jit(_sort)
+_cluster_key_j = _jit(_cluster_key)
+_lp_cluster_round_j = _jit(_lp_cluster_round, static=("mode",))
+_contract_nodes_j = _jit(_contract_nodes)
+_contract_edges_j = _jit(_contract_edges, static=("n_pad",))
+_fennel_order_j = _jit(_fennel_order)
+_initial_fennel_j = _jit(_initial_fennel, static=("w_c",), donate=(7,))
 _lp_refine_j = _jit(_lp_refine, static=("rounds", "mode"), donate=(8, 9))
 _project_j = _jit(_project, donate=(0,))
 
@@ -618,7 +740,7 @@ def multilevel_partition_jax(
     host-side.  `cfg` is a MultilevelConfig (imported lazily to avoid a
     module cycle with multilevel.py).
     """
-    with enable_x64():
+    with jax.enable_x64(True):
         n = g.n
         # floored at the block count: refine's capacity vector and accept's
         # target domain live in node-padded arrays, so n_pad must cover k
@@ -632,13 +754,15 @@ def multilevel_partition_jax(
         e_pad = bucket_size(int(g.indices.size),
                             minimum=min(8 * n_pad, 2048))
         src_h, dst_h, w_h = g.to_coo_padded(n_pad, e_pad)
-        esrc = jnp.asarray(src_h)
-        edst = jnp.asarray(dst_h)
+        esrc = jnp.asarray(src_h.astype(np.int32))
+        edst = jnp.asarray(dst_h.astype(np.int32))
         ew = jnp.asarray(w_h)
-        node_w = jnp.zeros(n_pad, dtype=jnp.float64).at[:n].set(
-            jnp.asarray(g.node_w.astype(np.float64)))
-        pin = jnp.full(n_pad, jnp.int64(-2)).at[:n].set(
-            jnp.asarray(pinned.astype(np.int64)))
+        node_w_h = np.zeros(n_pad, dtype=np.float64)
+        node_w_h[:n] = g.node_w
+        node_w = jnp.asarray(node_w_h)
+        pin_h = np.full(n_pad, -2, dtype=np.int32)
+        pin_h[:n] = pinned
+        pin = jnp.asarray(pin_h)
 
         free_total = pinned < 0
         n_free = int(free_total.sum())
@@ -672,13 +796,13 @@ def multilevel_partition_jax(
             base = _pick_mode(np_l, p.k, w_pad if level == 0 else None)
             return tuned("refine", np_l, p.k, base)
 
-        dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int64)
+        dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int32)
         dummy_wts = jnp.zeros((1, 8), dtype=jnp.float64)
         if "ell" in (cluster_mode(0, n_pad)[0], refine_mode(0, n_pad)[0]):
             nbr_h, wts_h, _ = g.to_ell_padded(
                 np.arange(n, dtype=np.int64),
                 row_bucket=n_pad, width_bucket=w_pad)
-            nbr = jnp.asarray(nbr_h.astype(np.int64))
+            nbr = jnp.asarray(nbr_h.astype(np.int32))
             wts = jnp.asarray(wts_h)
         else:
             nbr, wts = dummy_nbr, dummy_wts
@@ -698,14 +822,23 @@ def multilevel_partition_jax(
             c_mode, c_key = cluster_mode(level, cur_np)
             if c_key is not None:
                 t0 = time.perf_counter()
-            cluster = _lp_cluster_j(
-                cur[0], cur[1], cur[2], lvl_nbr, lvl_wts, cur[3], cur[4],
-                cur_n, max_cluster_w, iters=cfg.lp_iters, mode=c_mode)
+            cluster = jnp.arange(cur_np, dtype=jnp.int32)
+            cw = key_s = order = None
+            for r in range(cfg.lp_iters):
+                if r and c_mode == "sort":
+                    key_s, order = _sort_j(
+                        _cluster_key_j(cur[0], cur[1], cluster))
+                cluster, cw = _lp_cluster_round_j(
+                    cur[0], cur[1], cur[2], lvl_nbr, lvl_wts, cur[3], cur[4],
+                    cur_n, max_cluster_w, cluster if r else None, cw,
+                    key_s, order, mode=c_mode)
             if c_key is not None:
                 jax.block_until_ready(cluster)
                 _TUNER.record(c_key, c_mode, time.perf_counter() - t0)
-            es2, ed2, ew2, cw2, cpin2, node_map, nc_dev, ne_dev = _contract_j(
-                cur[0], cur[1], cur[2], cluster, cur[3], cur[4], cur_n)
+            cw2, cpin2, node_map, nc_dev, ekey = _contract_nodes_j(
+                cur[0], cur[1], cluster, cur[3], cur[4], cur_n)
+            es2, ed2, ew2, ne_dev = _contract_edges_j(
+                cur[2], *_sort_j(ekey), n_pad=cur_np)
             nc = int(nc_dev)
             if nc >= cfg.min_shrink * cur_n:
                 break
@@ -745,6 +878,7 @@ def multilevel_partition_jax(
         w_c = min(bucket_size(max_deg, minimum=64), cur_ep)
         labels, loads = _initial_fennel_j(
             cur[0], cur[1], cur[2], cur[3], cur[4], cur_n,
+            _fennel_order_j(cur[3], cur[4], cur_n),
             jnp.asarray(np.asarray(loads_base, dtype=np.float64)),
             alpha, gamma, cap, w_c=w_c)
         r_mode, r_key = refine_mode(level, cur_np)
@@ -777,4 +911,4 @@ def multilevel_partition_jax(
                 _TUNER.record(r_key, r_mode, time.perf_counter() - t0)
 
         # the single device->host transfer of the batch assignment
-        return np.asarray(labels[:n])
+        return np.asarray(labels[:n]).astype(np.int64)

@@ -26,7 +26,7 @@ def collective_matmul_allgather(x_local, w, axis_name: str):
     Ring schedule: at step s, multiply the chunk received s hops ago while
     forwarding the buffer to the next neighbor.
     """
-    n = jax.lax.psum(1, axis_name)  # axis size (jax.lax.axis_size needs jax>=0.6)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b_local = x_local.shape[0]
 
@@ -44,10 +44,8 @@ def collective_matmul_allgather(x_local, w, axis_name: str):
 
     out0 = jnp.zeros((b_local * n, w.shape[1]), x_local.dtype)
     # mark the accumulator as device-varying so the scan carry types match
-    # (its contents depend on axis_index from step 0 onward); pvary only
-    # exists under jax>=0.6 varying-type checking — older jax needs no mark
-    if hasattr(jax.lax, "pvary"):
-        out0 = jax.lax.pvary(out0, axis_name)
+    # (its contents depend on axis_index from step 0 onward)
+    out0 = jax.lax.pcast(out0, axis_name, to="varying")
     (buf, out), _ = jax.lax.scan(step, (x_local, out0), jnp.arange(n))
     return out
 

@@ -341,6 +341,26 @@ def _merge_graph(g: CSRGraph, block: np.ndarray, starts: np.ndarray,
 # --------------------------------------------------------------- the pool
 
 
+def _check_fork_safe(cfg: BuffCutConfig) -> None:
+    """Refuse a multilevel engine that would run jax in a forked child: an
+    XLA runtime does not survive fork, and on a TPU the parent already
+    holds the chip.  `jax` always runs jax, `ell` runs the histogram op
+    through XLA (the Pallas kernel on a TPU), and `auto` becomes `ell`
+    wherever the kernels are on."""
+    engine = cfg.ml.engine
+    device = engine in ("jax", "ell")
+    if engine == "auto":
+        from repro.kernels import ops  # the backend decides what auto runs
+
+        device = ops.USE_KERNELS_DEFAULT
+    if device:
+        raise ValueError(
+            f"shard_backend='process' cannot run the {engine!r} multilevel "
+            "engine here: it runs jax, and XLA runtimes do not survive fork; "
+            "use shard_backend='thread' or engine='sparse'"
+        )
+
+
 class ShardPool:
     """W shard workers with join-on-every-exit-path lifecycle.
 
@@ -363,6 +383,8 @@ class ShardPool:
         self.every = load_sync_every
         self.prefetch = prefetch_batches
         self.backend = backend
+        if backend == "process":
+            _check_fork_safe(cfg)
         self.merge_in_worker = merge_in_worker
         self.W = len(ranges)
         n = 0 if not ranges else ranges[-1][1]
@@ -492,8 +514,8 @@ class ShardPool:
                 )
                 with warnings.catch_warnings():
                     # jax warns about fork from its import-time hook; the
-                    # children never execute jax (engine='jax' is rejected
-                    # for this backend), so the fork is safe
+                    # children never execute jax (_check_fork_safe refused
+                    # every engine that would), so the fork is safe
                     warnings.filterwarnings(
                         "ignore", message=".*os.fork.*", category=RuntimeWarning
                     )
@@ -509,7 +531,12 @@ class ShardPool:
             t.start()
 
     def _join_all(self) -> None:
+        # a healthy worker is waited for however long its shard takes (a
+        # cold jax engine compiles for minutes); once the pool is aborted,
+        # each worker gets _JOIN_TIMEOUT_S to notice before the backstop
         for t in self._threads:
+            while t.is_alive() and self.shared.aborted is None:
+                t.join(timeout=_POLL_S)
             t.join(timeout=_JOIN_TIMEOUT_S)
         for t in self._threads:
             if t.is_alive():  # pragma: no cover - stuck worker backstop
@@ -633,12 +660,6 @@ def shard_partition(
     if backend not in SHARD_BACKENDS:
         raise ValueError(
             f"unknown shard backend {backend!r}: pick one of {SHARD_BACKENDS}"
-        )
-    if backend == "process" and cfg.ml.engine == "jax":
-        raise ValueError(
-            "shard_backend='process' cannot run the jax multilevel engine "
-            "(XLA runtimes do not survive fork); use shard_backend='thread' "
-            "or engine='sparse'"
         )
     stream = as_node_stream(source)
     n = stream.n

@@ -1,8 +1,11 @@
 """Pallas TPU kernels for the system's compute hot spots.
 
 Each kernel ships as <name>.py (pl.pallas_call + BlockSpec), a jit'd wrapper
-in ops.py, and a pure-jnp oracle in ref.py. Validated with interpret=True on
-CPU; compiled path engages automatically on TPU backends.
+in ops.py, and a pure-jnp oracle in ref.py.  CPU tests run the kernels in
+interpret mode; ops.py compiles them where the default backend is a TPU.
+Interpret mode cannot see what the TPU compiler refuses, so the histogram
+and Fennel kernels are also compiled for a described v5e
+(tests/test_tpu_compile.py) and run on the chip by chip_smoke.py.
 
 Public ops resolve lazily (PEP 562, same scheme as `repro.distributed`):
 `repro.core` reaches into this package for its multilevel engines, and a

@@ -5,17 +5,17 @@ counts[b, i] = Σ_w nbr_w[b, w] · [nbr_blk[b, w] == i]
 This is the inner op of every assignment decision in the system (Fennel
 gains, ANR updates, LP refinement) — the compute hot spot the paper's batch
 assignment spends its time in. The CPU implementation is a scatter; on TPU
-we reformulate as compare-and-accumulate over a (TB, WC, KC) tile so the
-VPU processes 8×128 lanes per cycle and the accumulator lives in VMEM
-across the whole W loop (single HBM write per output tile).
+it is a compare-and-accumulate over a (KC, TB) tile: node rows on the 128
+lanes, label columns on the sublanes, one neighbor column per step.
 
-Tiling: 2-D grid over (node tiles of TB rows) × (label tiles of KC
-columns); the W (padded max-degree) axis is walked in chunks of WC inside
-the kernel via fori_loop.  The K axis is tiled because the device-resident
-multilevel engine calls this with k = n_pad (cluster labels are node ids),
-and an untiled (TB, WC, K) one-hot intermediate would outgrow VMEM —
-8 MiB at k = 2048 against the ~16 MiB/core budget.  K is padded to a lane
-multiple (128) by the ops.py wrapper, so a 128-multiple KC always divides.
+Layout: the kernel takes the tiles transposed, (W, B), so neighbor column
+j of a node tile is the sublane row `ref[j]` (a ref slice the TPU loads
+directly — slicing a loaded value at a dynamic offset does not lower) and
+the histogram comes out (K, B).  Grid: (node tiles of TB) × (label tiles
+of KC) × (neighbor chunks of WC); the W axis is the last, sequential grid
+axis and the output tile stays resident in VMEM across it (one HBM write
+per tile).  The K axis is tiled because the host `ell` engine calls this
+with k in the thousands; the accumulator is KC·TB·4 B = 64 KiB.
 """
 from __future__ import annotations
 
@@ -24,67 +24,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-DEFAULT_TB = 128  # node rows per tile (8-sublane multiple)
-DEFAULT_WC = 8    # neighbor columns per inner step
-MAX_KC = 512      # label columns per grid tile (VMEM ceiling for the 3-D
-                  # one-hot: TB·WC·KC·4B = 2 MiB at the defaults)
+DEFAULT_TB = 128  # node rows per tile (lane width)
+MAX_WC = 128      # neighbor columns per grid step (sublane multiple)
+KC = 128          # label columns per grid tile (sublane multiple)
 
 
-def _pick_kc(k: int) -> int:
-    """Largest lane-multiple tile ≤ MAX_KC that divides k (k is a 128
-    multiple from ops.py, so 128 always divides)."""
-    for kc in (MAX_KC, 384, 256, 128):
-        if k % kc == 0:
-            return kc
-    return k
+def pick_wc(w: int) -> int:
+    """Neighbor chunk for a width already padded by ops.py (a multiple of
+    8, and of MAX_WC above it)."""
+    return w if w <= MAX_WC else MAX_WC
 
 
-def _histogram_kernel(blk_ref, w_ref, out_ref, *, kc: int, wc: int):
-    tb, w_total = blk_ref.shape
-    acc = jnp.zeros((tb, kc), dtype=jnp.float32)
-    # absolute label ids covered by this K tile
-    k_off = pl.program_id(1) * kc
-    ids = k_off + jax.lax.broadcasted_iota(jnp.int32, (tb, wc, kc), 2)
+def _histogram_kernel(blk_ref, w_ref, out_ref, *, kc: int):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    def body(step, acc):
-        start = step * wc
-        blk = jax.lax.dynamic_slice(blk_ref[...], (0, start), (tb, wc))
-        wts = jax.lax.dynamic_slice(w_ref[...], (0, start), (tb, wc))
-        onehot = (blk[:, :, None] == ids).astype(jnp.float32)
-        return acc + jnp.sum(onehot * wts[:, :, None], axis=1)
+    wc, tb = blk_ref.shape
+    ids = pl.program_id(1) * kc + jax.lax.broadcasted_iota(jnp.int32, (kc, tb), 0)
 
-    n_steps = w_total // wc
-    acc = jax.lax.fori_loop(0, n_steps, body, acc)
-    out_ref[...] = acc
+    def body(j, acc):
+        blk = blk_ref[pl.ds(j, 1), :]  # (1, tb): neighbor column j
+        wts = w_ref[pl.ds(j, 1), :]
+        return acc + jnp.where(blk == ids, wts, 0.0)
+
+    # int32 bounds: the jax engine calls this under x64, and an int64
+    # loop index would reach the TPU lowering
+    out_ref[...] = jax.lax.fori_loop(jnp.int32(0), jnp.int32(wc), body,
+                                     out_ref[...])
 
 
 def ell_histogram(
-    nbr_blk: jnp.ndarray,
-    nbr_w: jnp.ndarray,
+    nbr_blk_t: jnp.ndarray,
+    nbr_w_t: jnp.ndarray,
     k: int,
     *,
     tb: int = DEFAULT_TB,
-    wc: int = DEFAULT_WC,
-    kc: int | None = None,
     interpret: bool = True,
 ) -> jnp.ndarray:
-    """counts (B, k) float32. Caller pads B to a tb multiple, W to a wc
-    multiple and k to a 128 multiple (see ops.py)."""
-    b, w = nbr_blk.shape
-    if kc is None:
-        kc = _pick_kc(k)
-    assert b % tb == 0 and w % wc == 0 and k % kc == 0, (b, w, k, tb, wc, kc)
-    kernel = functools.partial(_histogram_kernel, kc=kc, wc=wc)
+    """counts (k, B) float32 from transposed (W, B) tiles.  The caller pads
+    B to a tb multiple, W per `pick_wc` and k to a KC multiple (ops.py)."""
+    w, b = nbr_blk_t.shape
+    wc = pick_wc(w)
+    assert b % tb == 0 and w % wc == 0 and w % 8 == 0 and k % KC == 0, (
+        b, w, k, tb, wc)
+    kernel = functools.partial(_histogram_kernel, kc=KC)
     return pl.pallas_call(
         kernel,
-        grid=(b // tb, k // kc),
+        grid=(b // tb, k // KC, w // wc),
         in_specs=[
-            pl.BlockSpec((tb, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((tb, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((wc, tb), lambda i, j, s: (s, i)),
+            pl.BlockSpec((wc, tb), lambda i, j, s: (s, i)),
         ],
-        out_specs=pl.BlockSpec((tb, kc), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
+        out_specs=pl.BlockSpec((KC, tb), lambda i, j, s: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((k, b), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(nbr_blk, nbr_w)
+        name="ell_histogram",
+    )(nbr_blk_t, nbr_w_t)

@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ell_histogram import DEFAULT_TB, DEFAULT_WC
+from repro.kernels.ell_histogram import DEFAULT_TB, pick_wc
 
 _NEG_INF = -1e30
 
@@ -116,73 +117,93 @@ def fennel_gain_sequential(
 
 
 def _fennel_kernel(
-    blk_ref, w_ref, loads_ref, node_w_ref, best_ref, score_ref,
-    *, k: int, wc: int, alpha: float, gamma: float, cap: float,
+    blk_ref, w_ref, loads_ref, penalty_ref, node_w_ref, best_ref, score_ref,
+    acc_ref, *, cap: float,
 ):
-    tb, w_total = blk_ref.shape
-    ids = jax.lax.broadcasted_iota(jnp.int32, (tb, wc, k), 2)
+    k, tb = acc_ref.shape
 
-    def body(step, acc):
-        start = step * wc
-        blk = jax.lax.dynamic_slice(blk_ref[...], (0, start), (tb, wc))
-        wts = jax.lax.dynamic_slice(w_ref[...], (0, start), (tb, wc))
-        onehot = (blk[:, :, None] == ids).astype(jnp.float32)
-        return acc + jnp.sum(onehot * wts[:, :, None], axis=1)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    counts = jax.lax.fori_loop(
-        0, w_total // wc, body, jnp.zeros((tb, k), dtype=jnp.float32)
-    )
-    loads = loads_ref[0, :]  # (k,)
-    penalty = alpha * gamma * jnp.power(jnp.maximum(loads, 0.0), gamma - 1.0)
-    score = counts - penalty[None, :]
-    feasible = (loads[None, :] + node_w_ref[...]) <= cap  # (tb, k)
-    masked = jnp.where(feasible, score, _NEG_INF)
-    # argmax with lowest-id tie-break == jnp.argmax semantics
-    best = jnp.argmax(masked, axis=1).astype(jnp.int32)
-    any_ok = feasible.any(axis=1)
-    fallback = jnp.argmin(loads).astype(jnp.int32)
-    best = jnp.where(any_ok, best, fallback)
-    best_ref[...] = best[:, None]
-    score_ref[...] = jnp.max(masked, axis=1)[:, None]
+    ids = jax.lax.broadcasted_iota(jnp.int32, (k, tb), 0)
+
+    def body(j, acc):
+        blk = blk_ref[pl.ds(j, 1), :]  # (1, tb): neighbor column j
+        wts = w_ref[pl.ds(j, 1), :]
+        return acc + jnp.where(blk == ids, wts, 0.0)
+
+    acc_ref[...] = jax.lax.fori_loop(jnp.int32(0), jnp.int32(blk_ref.shape[0]),
+                                     body, acc_ref[...])
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _decide():
+        # f32 constants spelled out: under x64 a Python float would be an
+        # f64 constant, and Mosaic cannot truncate one
+        score = acc_ref[...] - penalty_ref[...]                   # (k, tb)
+        feasible = (loads_ref[...] + node_w_ref[...]) <= jnp.float32(cap)
+        masked = jnp.where(feasible, score, jnp.float32(_NEG_INF))
+        best_s = jnp.max(masked, axis=0, keepdims=True)          # (1, tb)
+        # first max == jnp.argmax's lowest-id tie-break
+        best = jnp.min(jnp.where(masked == best_s, ids, jnp.int32(k)), axis=0,
+                       keepdims=True)
+        best_ref[...] = best
+        score_ref[...] = best_s
+
+
+# block index maps: int32 literals, because under x64 a bare 0 is an i64
+# block index, which Mosaic cannot lower
+def _first_block(i, s):
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _row_block(i, s):
+    return jnp.int32(0), i
 
 
 def fennel_gain(
-    nbr_blk: jnp.ndarray,
-    nbr_w: jnp.ndarray,
+    nbr_blk_t: jnp.ndarray,
+    nbr_w_t: jnp.ndarray,
     loads: jnp.ndarray,
+    penalty: jnp.ndarray,
     node_w: jnp.ndarray,
     *,
-    alpha: float,
-    gamma: float,
     cap: float,
     tb: int = DEFAULT_TB,
-    wc: int = DEFAULT_WC,
     interpret: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (best_block (B,), best_score (B,)). Shapes pre-padded by ops."""
-    b, w = nbr_blk.shape
+    """(best block (B,), best masked score (B,)) from transposed (W, B)
+    tiles; `penalty` is α·γ·load^(γ−1) per block.  Shapes pre-padded by
+    ops.py.  Rows with no feasible block come back with score -1e30; the
+    caller applies the least-loaded fallback."""
+    w, b = nbr_blk_t.shape
     k = loads.shape[0]
-    assert b % tb == 0 and w % wc == 0
-    kernel = functools.partial(
-        _fennel_kernel, k=k, wc=wc, alpha=float(alpha), gamma=float(gamma), cap=float(cap)
-    )
+    wc = pick_wc(w)
+    assert b % tb == 0 and w % wc == 0 and w % 8 == 0 and k % 8 == 0
+    kernel = functools.partial(_fennel_kernel, cap=float(cap))
     best, score = pl.pallas_call(
         kernel,
-        grid=(b // tb,),
+        grid=(b // tb, w // wc),
         in_specs=[
-            pl.BlockSpec((tb, w), lambda i: (i, 0)),
-            pl.BlockSpec((tb, w), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (0, 0)),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
+            pl.BlockSpec((wc, tb), lambda i, s: (s, i)),
+            pl.BlockSpec((wc, tb), lambda i, s: (s, i)),
+            pl.BlockSpec((k, 1), _first_block),
+            pl.BlockSpec((k, 1), _first_block),
+            pl.BlockSpec((1, tb), _row_block),
         ],
         out_specs=[
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, tb), _row_block),
+            pl.BlockSpec((1, tb), _row_block),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((1, b), jnp.int32),
+            jax.ShapeDtypeStruct((1, b), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((k, tb), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(nbr_blk, nbr_w, loads.reshape(1, k), node_w.reshape(b, 1))
-    return best[:, 0], score[:, 0]
+        name="fennel_gain",
+    )(nbr_blk_t, nbr_w_t, loads.reshape(k, 1), penalty.reshape(k, 1),
+      node_w.reshape(1, b))
+    return best[0], score[0]

@@ -1,10 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Each op pads its inputs to hardware-aligned tiles, dispatches to the Pallas
-kernel (interpret=True on CPU — this container; compiled on real TPUs), and
-exposes a `use_kernel=False` escape hatch that routes to the pure-jnp
-reference (used by the dry-run lowering path, where XLA fusion of the ref
-formulation is what the roofline sees, and by hypothesis tests).
+Each op pads its inputs to hardware-aligned tiles and dispatches to the
+Pallas kernel: compiled where the default backend is a TPU, interpreted
+elsewhere.  `use_kernel=None` picks the kernel on a TPU and the pure-jnp
+reference (kernels/ref.py) under XLA elsewhere; `use_kernel=False` forces
+the reference.  The histogram and Fennel kernels are compiled for a
+described v5e by tests/test_tpu_compile.py, and chip_smoke.py runs them
+on the chip against the references.
 """
 from __future__ import annotations
 
@@ -14,7 +16,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
+from repro.kernels.ell_histogram import KC as _KC
+from repro.kernels.ell_histogram import MAX_WC as _MAX_WC
 from repro.kernels.ell_histogram import ell_histogram as _ell_kernel
+from repro.kernels.fennel_gain import _NEG_INF
 from repro.kernels.fennel_gain import fennel_gain as _fennel_kernel
 from repro.kernels.embedding_bag import embedding_bag as _bag_kernel
 from repro.kernels.swa_attention import swa_attention_decode as _swa_kernel
@@ -36,6 +41,15 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0):
     return jnp.pad(x, pads, constant_values=value)
 
 
+def _ell_tiles_t(nbr_blk, nbr_w):
+    """Pad ELL tiles for the kernels (rows to 128, width to 8 or to MAX_WC
+    chunks) and transpose them to the kernels' (W, B) layout."""
+    w_mult = 8 if nbr_blk.shape[1] <= _MAX_WC else _MAX_WC
+    blk = _pad_to(_pad_to(nbr_blk.astype(jnp.int32), 1, w_mult, -1), 0, 128, -1)
+    wts = _pad_to(_pad_to(nbr_w.astype(jnp.float32), 1, w_mult, 0.0), 0, 128, 0.0)
+    return blk.T, wts.T
+
+
 @partial(jax.jit, static_argnames=("k", "use_kernel", "interpret"))
 def block_histogram(
     nbr_blk: jnp.ndarray,
@@ -53,12 +67,11 @@ def block_histogram(
         use_kernel = USE_KERNELS_DEFAULT
     if not use_kernel:
         return _ref.ell_histogram_ref(nbr_blk, nbr_w, k)
-    b0, w0 = nbr_blk.shape
-    kp = max(((k + 127) // 128) * 128, 128)
-    blk = _pad_to(_pad_to(nbr_blk, 1, 8, -1), 0, 128, -1)
-    wts = _pad_to(_pad_to(nbr_w, 1, 8, 0.0), 0, 128, 0.0)
-    out = _ell_kernel(blk, wts, kp, interpret=interpret)
-    return out[:b0, :k]
+    b0 = nbr_blk.shape[0]
+    kp = max(-(-k // _KC) * _KC, _KC)
+    blk_t, wts_t = _ell_tiles_t(nbr_blk, nbr_w)
+    out = _ell_kernel(blk_t, wts_t, kp, interpret=interpret)
+    return out[:k, :b0].T
 
 
 @partial(
@@ -88,17 +101,19 @@ def fennel_choose_batch(
         )
     b0 = nbr_blk.shape[0]
     k0 = loads.shape[0]
-    kp = max(((k0 + 127) // 128) * 128, 128)
-    blk = _pad_to(_pad_to(nbr_blk, 1, 8, -1), 0, 128, -1)
-    wts = _pad_to(_pad_to(nbr_w, 1, 8, 0.0), 0, 128, 0.0)
+    kp = -(-k0 // 8) * 8
+    blk_t, wts_t = _ell_tiles_t(nbr_blk, nbr_w)
     # padded blocks get load=+cap so they are never feasible/chosen
     loads_p = jnp.full((kp,), jnp.float32(cap) * 2 + 1, dtype=jnp.float32)
     loads_p = loads_p.at[:k0].set(loads.astype(jnp.float32))
+    penalty = alpha * gamma * jnp.power(jnp.maximum(loads_p, 0.0), gamma - 1.0)
     node_w_p = _pad_to(node_w.astype(jnp.float32), 0, 128, 0.0)
     best, score = _fennel_kernel(
-        blk, wts, loads_p, node_w_p,
-        alpha=alpha, gamma=gamma, cap=cap, interpret=interpret,
+        blk_t, wts_t, loads_p, penalty, node_w_p, cap=cap, interpret=interpret,
     )
+    # no feasible block: the least-loaded one (fennel_gain_ref's fallback)
+    fallback = jnp.argmin(loads.astype(jnp.float32)).astype(jnp.int32)
+    best = jnp.where(score > _NEG_INF, best, fallback)
     return best[:b0], score[:b0]
 
 

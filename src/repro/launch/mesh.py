@@ -8,14 +8,11 @@ from __future__ import annotations
 
 import jax  # repro: noqa RPR001 -- launch-time mesh module; only reached from train-arch entry points
 
-try:  # jax >= 0.5 (explicit-sharding API); older jax has no AxisType
-    from jax.sharding import AxisType  # repro: noqa RPR001 -- launch-time mesh module
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType  # repro: noqa RPR001 -- launch-time mesh module
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    return {} if AxisType is None else {"axis_types": (AxisType.Auto,) * n_axes}
+    return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

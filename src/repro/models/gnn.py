@@ -310,7 +310,6 @@ def sage_fullgraph_halo_loss(params, batch, cfg: GraphSAGEConfig, mesh, dp_axes)
                                   >= N_loc means frontier slot
       edge_dst     (E,)  int32  — LOCAL dst row in [0, N_loc)
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
@@ -338,12 +337,12 @@ def sage_fullgraph_halo_loss(params, batch, cfg: GraphSAGEConfig, mesh, dp_axes)
         den = jax.lax.psum(nmask.sum(), dp_axes)
         return num / jnp.maximum(den, 1.0)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(dp, None), P(dp), P(dp), P(dp), P(dp), P(dp), P(dp)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(
         params, batch["x"], batch["frontier_own"], batch["edge_src"],
         batch["edge_dst"], batch["edge_mask"], batch["labels"],

@@ -235,7 +235,6 @@ def _moe_ffn_spmd(x3, layer, cfg: TransformerConfig):
     x3: the UNFLATTENED (B, S, d) activations; tokens are flattened inside
     the shard_map body so the token layout is whatever (B, S) tiling the
     surrounding program already uses."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _MOE_SPMD["mesh"]
@@ -265,13 +264,13 @@ def _moe_ffn_spmd(x3, layer, cfg: TransformerConfig):
                          cap_loc, x_loc.dtype)
         return y.reshape(bl, sl, d)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(), P(ea, None, None), P(ea, None, None),
                   P(ea, None, None)),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x3, layer["router"], layer["moe_w1"], layer["moe_w3"], layer["moe_w2"])
 
 

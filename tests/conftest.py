@@ -1,12 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here on purpose — tests must see the
 host's real device count (1); only launch/dryrun.py forces 512 devices."""
-try:  # property tests degrade to a seeded random-example runner without it
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
-
 import pytest
 
 

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.sharding import (
     lm_sharding_rules,
@@ -56,11 +55,11 @@ def test_collective_matmul_matches_reference():
     mesh = jax.make_mesh((1,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 16))
     w = jax.random.normal(jax.random.PRNGKey(1), (16, 4))
-    f1 = shard_map(
+    f1 = jax.shard_map(
         lambda xl, w: collective_matmul_allgather(xl, w, "model"),
         mesh=mesh, in_specs=(P("model"), P()), out_specs=P("model"),
     )
-    f2 = shard_map(
+    f2 = jax.shard_map(
         lambda xl, w: allgather_matmul_reference(xl, w, "model"),
         mesh=mesh, in_specs=(P("model"), P()), out_specs=P("model"),
     )

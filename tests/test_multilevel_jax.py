@@ -204,3 +204,40 @@ def test_agg_autotune_off_by_default():
                          MultilevelConfig(engine="jax"))
     assert mlj.agg_decisions() == {}
     assert not mlj._TUNER._samples
+
+
+@pytest.mark.parametrize("n_pad", [1 << 10, 1 << 16, 1 << 17])
+def test_sort_aggregation_matches_numpy(n_pad):
+    """The sort aggregation's packed (src, label) keys: u32 up to 2^16
+    nodes — where the key (n_pad-1, n_pad-1) shares the sentinel's bits —
+    and u64 beyond (δ = 65,536 batches), against a numpy group-by."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(n_pad)
+    e = 4096
+    src = np.sort(rng.integers(n_pad - 64, n_pad, e)).astype(np.int32)
+    dst = rng.integers(n_pad - 64, n_pad, e).astype(np.int32)
+    w = rng.integers(1, 4, e).astype(np.float64)
+    src[-16:] = dst[-16:] = n_pad  # padding entries
+    labels = np.where(rng.random(n_pad) < 0.5, n_pad - 1,
+                      rng.integers(n_pad - 8, n_pad, n_pad)).astype(np.int32)
+    with jax.enable_x64(True):
+        cur, best_w, best_lab = (np.asarray(a) for a in mlj._agg_sort(
+            jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w),
+            jnp.asarray(labels), jnp.asarray(labels), None, n_pad))
+    totals: dict = {}
+    for s, d, x in zip(src[:-16], dst[:-16], w[:-16]):
+        key = (int(s), int(labels[d]))
+        totals[key] = totals.get(key, 0.0) + x
+    for v in np.unique(src[:-16]):
+        own = {lab: t for (s, lab), t in totals.items() if s == v}
+        assert cur[v] == own.get(int(labels[v]), 0.0)
+        cand = {lab: t for lab, t in own.items() if lab != labels[v]}
+        if cand:
+            top = max(cand.values())
+            assert best_w[v] == top
+            assert best_lab[v] == min(lab for lab, t in cand.items()
+                                      if t == top)
+        else:
+            assert best_w[v] == -np.inf

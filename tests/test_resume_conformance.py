@@ -101,18 +101,22 @@ def test_every_snapshot_resumes_bit_identically(
 @settings(max_examples=5, deadline=None)
 @given(st.integers(0, 10**6), st.sampled_from(["buffcut", "buffcut-vec", "buffcut-pipe"]))
 def test_property_random_kill_point_resumes(sources, tmp_path_factory,
-                                            monkeypatch, seed, driver):
+                                            seed, driver):
     """Randomized crash point: kill the run at an arbitrary snapshot index
     and the resumed labels must still match an uninterrupted run."""
     src = sources["packed"]
     base = partition(src, driver=driver, restream_passes=1,
                      restream_order="stream", **_KW)
     d = tmp_path_factory.mktemp("kill")
-    copies, stop = _capture_snapshots(monkeypatch, str(d))
-    cp = str(d / "run.ckpt")
-    partition(src, driver=driver, restream_passes=1, restream_order="stream",
-              checkpoint_path=cp, checkpoint_every=1, **_KW)
-    stop()
+    # a patch scoped to this example: hypothesis reruns the body, so a
+    # function-scoped fixture would leak across examples
+    with pytest.MonkeyPatch.context() as mp:
+        copies, stop = _capture_snapshots(mp, str(d))
+        cp = str(d / "run.ckpt")
+        partition(src, driver=driver, restream_passes=1,
+                  restream_order="stream", checkpoint_path=cp,
+                  checkpoint_every=1, **_KW)
+        stop()
     snap, kind = copies[seed % len(copies)]
     res = resume(snap)
     np.testing.assert_array_equal(res.labels, base.labels)
@@ -212,13 +216,15 @@ def test_pipelined_checkpoint_failure_leaves_no_threads(graph, tmp_path,
 def _flaky_jax_multilevel(monkeypatch):
     """Patch multilevel_partition so every jax-engine call dies the way a
     lost accelerator does; sparse calls run for real."""
+    import jax
+
     import repro.core.multilevel as ml
 
     real = ml.multilevel_partition
 
     def flaky(g, pinned, p, loads_base, cfg=None):
         if cfg is not None and cfg.engine == "jax":
-            raise RuntimeError("injected: XLA backend lost")
+            raise jax.errors.JaxRuntimeError("injected: XLA backend lost")
         return real(g, pinned, p, loads_base, cfg)
 
     monkeypatch.setattr(ml, "multilevel_partition", flaky)
@@ -239,6 +245,28 @@ def test_jax_engine_failure_falls_back_to_sparse(graph, monkeypatch):
     assert stats.engine_fallbacks == base_stats.n_batches + base_stats.n_hubs \
         or stats.engine_fallbacks >= 1
     assert base_stats.engine_fallbacks == 0
+
+
+@pytest.mark.parametrize("exc", [ImportError, NotImplementedError])
+def test_jax_engine_path_errors_propagate(graph, monkeypatch, exc):
+    """A broken device path (an import or lowering failure) is not a lost
+    device: no fallback hides it behind a host run."""
+    import dataclasses
+
+    import repro.core.multilevel as ml
+
+    real = ml.multilevel_partition
+
+    def broken(g, pinned, p, loads_base, cfg=None):
+        if cfg is not None and cfg.engine == "jax":
+            raise exc("injected: device path broken")
+        return real(g, pinned, p, loads_base, cfg)
+
+    monkeypatch.setattr(ml, "multilevel_partition", broken)
+    cfg = BuffCutConfig(**_KW)
+    cfg = dataclasses.replace(cfg, ml=dataclasses.replace(cfg.ml, engine="jax"))
+    with pytest.raises(exc, match="device path broken"):
+        _buffcut_partition(NodeStream(graph), cfg)
 
 
 def test_sparse_engine_failure_still_propagates(graph, monkeypatch):

@@ -10,8 +10,7 @@ Pins, per DESIGN.md §13's determinism contract:
   recomputation, and the merged `block_loads` are exact;
 * post-restream (priority) cut within a pinned tolerance of single-worker;
 * `SharedLoads` property: any interleaving of per-worker delta publishes
-  converges to the exact pinned-order global loads (hypothesis, with the
-  `_hypothesis_stub` fallback so tier-1 runs without hypothesis).
+  converges to the exact pinned-order global loads (hypothesis).
 """
 import threading
 
@@ -133,9 +132,29 @@ def test_thread_process_backend_parity(workers, base_graph):
     assert info["backend"] == "process"
 
 
-def test_process_backend_rejects_jax_engine(base_graph):
+@pytest.mark.parametrize("engine", ["jax", "ell", "auto"])
+def test_process_backend_rejects_jax_engine(engine, base_graph, monkeypatch):
+    """Every engine that runs jax is refused before anything forks; `auto`
+    runs it wherever the kernels are on, as on a TPU."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "USE_KERNELS_DEFAULT", True)
     with pytest.raises(ValueError, match="fork"):
-        shard_partition(base_graph, _cfg("jax"), workers=2, backend="process")
+        shard_partition(base_graph, _cfg(engine), workers=2, backend="process")
+
+
+@pytest.mark.parametrize("backend", ["thread", "process"])
+def test_slow_shard_is_not_cut_short(backend, base_graph, monkeypatch):
+    """The join timeout only bounds workers of an aborted pool: a healthy
+    shard that takes longer (a cold jax engine compiles for minutes) still
+    finishes, with the same labels."""
+    import repro.distributed.shard_driver as sd
+
+    cfg = _cfg()
+    want, _, _ = shard_partition(base_graph, cfg, workers=2, backend=backend)
+    monkeypatch.setattr(sd, "_JOIN_TIMEOUT_S", 1e-3)
+    got, _, _ = shard_partition(base_graph, cfg, workers=2, backend=backend)
+    assert np.array_equal(want, got)
 
 
 def test_disk_matches_memory_sharded(base_graph, disk_files):

@@ -1,0 +1,116 @@
+"""Ahead-of-time compiles for a described TPU v5e: no chip needed.
+
+Interpret mode hides what the TPU compiler refuses (a dynamic slice of a
+loaded value, an int64 index inside a kernel, a tile over the VMEM
+budget), and CPU runs never see it.  These tests compile the Pallas kernels
+and the level-0 refine stage for a v5e at the shapes the main path admits
+at paper batch size: 65,536 rows (the pow2 bucket of a δ = 32,768 batch
+plus its k = 32 aux nodes), ELL widths up to the 2^24 tile-volume cap, k =
+32 (128 label columns) for refine and a K-tiled cluster-label domain.  The
+engine runs under x64, so every kernel compiles with and without it.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and a test worker that
+collected different tests than its siblings would stop the whole run.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+ROWS = 65536    # bucket of a 32,768-node batch plus 32 aux nodes
+E_PAD = 1 << 20  # edge bucket of such a batch model graph
+K = 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_cache():
+    """A described chip's programs are written to the persistent cache but
+    can never be read back (the next read warns): keep them out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("rows,width,k", [
+    (ROWS, 8, K),      # narrowest ELL tile
+    (ROWS, 256, K),    # widest tile under the volume cap at this row count
+    (4096, 64, 4096),  # K-tiled cluster labels (label id = node id)
+])
+def test_ell_histogram_compiles(one_chip, no_cache, rows, width, k, x64):
+    with jax.enable_x64(x64):
+        compiled = jax.jit(
+            lambda b, w: ops.block_histogram(b, w, k, use_kernel=True,
+                                             interpret=False)
+        ).lower(_spec(one_chip, (rows, width), jnp.int32),
+                _spec(one_chip, (rows, width), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("rows,width", [(ROWS // 2, 256), (ROWS, 8)])
+def test_fennel_gain_compiles(one_chip, no_cache, rows, width, x64):
+    with jax.enable_x64(x64):
+        compiled = jax.jit(
+            lambda b, w, loads, nw: ops.fennel_choose_batch(
+                b, w, loads, nw, alpha=0.5, gamma=1.5, cap=1024.0,
+                use_kernel=True, interpret=False)
+        ).lower(_spec(one_chip, (rows, width), jnp.int32),
+                _spec(one_chip, (rows, width), jnp.float32),
+                _spec(one_chip, (K,), jnp.float32),
+                _spec(one_chip, (rows,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode,width", [("ell", 256), ("dense", 8)])
+def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width):
+    """The refine stage of a δ = 32,768 batch at level 0: the ELL mode runs
+    the Pallas histogram inside the stage, dense the scatter twin; both
+    with the sort-free capacity acceptance, all under x64."""
+    import repro.core.multilevel_jax as mlj
+
+    # the engine turns the kernels on from the backend it runs on; the
+    # described chip is not that backend, so say it here
+    monkeypatch.setattr(ops, "USE_KERNELS_DEFAULT", True)
+    with jax.enable_x64(True):
+        def s(shape, dt):
+            return _spec(one_chip, shape, dt)
+
+        i32, f64 = jnp.int32, jnp.float64
+        compiled = jax.jit(
+            mlj._lp_refine, static_argnames=("rounds", "mode"),
+        ).lower(
+            s((E_PAD,), i32), s((E_PAD,), i32), s((E_PAD,), f64),
+            s((ROWS, width), i32), s((ROWS, width), f64),
+            s((ROWS,), f64), s((ROWS,), i32), s((), i32),
+            s((ROWS,), i32), s((K,), f64), s((), f64),
+            rounds=3, mode=mode,
+        ).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == (mode == "ell")
