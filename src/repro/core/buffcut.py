@@ -157,6 +157,9 @@ class StreamStats:
     io_retries: int = 0               # transient stream-IO errors absorbed
     engine_fallbacks: int = 0         # batches degraded jax -> sparse engine
     checkpoints_written: int = 0      # crash-safe snapshots persisted
+    # host-clock seconds per stage span of the pipelined driver's T2 and
+    # T3 threads (core/spans.py names; empty for the other drivers)
+    stage_s: dict = dataclasses.field(default_factory=dict)
 
     def note_engine_fallback(self) -> None:
         """Bound as the drivers' `on_fallback` callback (a lambda cannot

@@ -45,6 +45,7 @@ import jax.numpy as jnp  # repro: noqa RPR001 -- jax engine module
 import numpy as np
 
 from repro.core.fennel import FennelParams
+from repro.core.spans import span
 from repro.core.multilevel import _ELL_VOLUME_CAP as ELL_VOLUME_CAP
 from repro.core.multilevel import _ELL_WIDTH_CAP as ELL_WIDTH_CAP
 from repro.graphs.csr import CSRGraph, bucket_size
@@ -741,71 +742,72 @@ def multilevel_partition_jax(
     module cycle with multilevel.py).
     """
     with jax.enable_x64(True):
-        n = g.n
-        # floored at the block count: refine's capacity vector and accept's
-        # target domain live in node-padded arrays, so n_pad must cover k
-        # even when the graph is smaller than the partition (k > n)
-        n_pad = bucket_size(max(n, p.k))
-        # edge bucket floored at 8·n_pad for stream-scale graphs: batch
-        # models in one stream have near-constant node counts but noisy
-        # edge counts, and the floor absorbs that noise into a single
-        # compilation.  The cap keeps the floor from inflating large or
-        # coarse graphs whose true edge count is what matters.
-        e_pad = bucket_size(int(g.indices.size),
-                            minimum=min(8 * n_pad, 2048))
-        src_h, dst_h, w_h = g.to_coo_padded(n_pad, e_pad)
-        esrc = jnp.asarray(src_h.astype(np.int32))
-        edst = jnp.asarray(dst_h.astype(np.int32))
-        ew = jnp.asarray(w_h)
-        node_w_h = np.zeros(n_pad, dtype=np.float64)
-        node_w_h[:n] = g.node_w
-        node_w = jnp.asarray(node_w_h)
-        pin_h = np.full(n_pad, -2, dtype=np.int32)
-        pin_h[:n] = pinned
-        pin = jnp.asarray(pin_h)
+        with span("vcycle.pack"):
+            n = g.n
+            # floored at the block count: refine's capacity vector and accept's
+            # target domain live in node-padded arrays, so n_pad must cover k
+            # even when the graph is smaller than the partition (k > n)
+            n_pad = bucket_size(max(n, p.k))
+            # edge bucket floored at 8·n_pad for stream-scale graphs: batch
+            # models in one stream have near-constant node counts but noisy
+            # edge counts, and the floor absorbs that noise into a single
+            # compilation.  The cap keeps the floor from inflating large or
+            # coarse graphs whose true edge count is what matters.
+            e_pad = bucket_size(int(g.indices.size),
+                                minimum=min(8 * n_pad, 2048))
+            src_h, dst_h, w_h = g.to_coo_padded(n_pad, e_pad)
+            esrc = jnp.asarray(src_h.astype(np.int32))
+            edst = jnp.asarray(dst_h.astype(np.int32))
+            ew = jnp.asarray(w_h)
+            node_w_h = np.zeros(n_pad, dtype=np.float64)
+            node_w_h[:n] = g.node_w
+            node_w = jnp.asarray(node_w_h)
+            pin_h = np.full(n_pad, -2, dtype=np.int32)
+            pin_h[:n] = pinned
+            pin = jnp.asarray(pin_h)
 
-        free_total = pinned < 0
-        n_free = int(free_total.sum())
-        total_free_w = float(g.node_w[free_total].astype(np.float64).sum())
-        max_cluster_w = max(total_free_w / max(2 * p.k, 16),
-                            float(g.node_w.max(initial=1.0)))
+            free_total = pinned < 0
+            n_free = int(free_total.sum())
+            total_free_w = float(g.node_w[free_total].astype(np.float64).sum())
+            max_cluster_w = max(total_free_w / max(2 * p.k, 16),
+                                float(g.node_w.max(initial=1.0)))
 
-        # level 0 may use the ELL tiles packed once per batch; free-node
-        # degrees bound the width (pinned aux rows are never movers, so
-        # their truncation is harmless)
-        free_deg = int(np.max(np.diff(g.indptr)[free_total], initial=1))
-        w_pad = bucket_size(free_deg, minimum=8)
+            # level 0 may use the ELL tiles packed once per batch; free-node
+            # degrees bound the width (pinned aux rows are never movers, so
+            # their truncation is harmless)
+            free_deg = int(np.max(np.diff(g.indptr)[free_total], initial=1))
+            w_pad = bucket_size(free_deg, minimum=8)
 
-        autotune = bool(getattr(cfg, "agg_autotune", False))
+            autotune = bool(getattr(cfg, "agg_autotune", False))
 
-        def tuned(phase: str, np_l: int, l_pad: int, base: str):
-            """(mode, timing key | None) — key is non-None while the tuner
-            still wants a blocking measurement for this call."""
-            if (not autotune or MODE_OVERRIDE is not None or base == "ell"
-                    or np_l * l_pad > _AUTOTUNE_DENSE_CAP):
-                return base, None
-            key = (phase, np_l, l_pad)
-            mode, explore = _TUNER.choose(key, ("dense", "sort"))
-            return mode, (key if explore else None)
+            def tuned(phase: str, np_l: int, l_pad: int, base: str):
+                """(mode, timing key | None) — key is non-None while the tuner
+                still wants a blocking measurement for this call."""
+                if (not autotune or MODE_OVERRIDE is not None or base == "ell"
+                        or np_l * l_pad > _AUTOTUNE_DENSE_CAP):
+                    return base, None
+                key = (phase, np_l, l_pad)
+                mode, explore = _TUNER.choose(key, ("dense", "sort"))
+                return mode, (key if explore else None)
 
-        def cluster_mode(level: int, np_l: int):
-            base = _pick_mode(np_l, np_l, w_pad if level == 0 else None)
-            return tuned("cluster", np_l, np_l, base)
+            def cluster_mode(level: int, np_l: int):
+                base = _pick_mode(np_l, np_l, w_pad if level == 0 else None)
+                return tuned("cluster", np_l, np_l, base)
 
-        def refine_mode(level: int, np_l: int):
-            base = _pick_mode(np_l, p.k, w_pad if level == 0 else None)
-            return tuned("refine", np_l, p.k, base)
+            def refine_mode(level: int, np_l: int):
+                base = _pick_mode(np_l, p.k, w_pad if level == 0 else None)
+                return tuned("refine", np_l, p.k, base)
 
-        dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int32)
-        dummy_wts = jnp.zeros((1, 8), dtype=jnp.float64)
-        if "ell" in (cluster_mode(0, n_pad)[0], refine_mode(0, n_pad)[0]):
-            nbr_h, wts_h, _ = g.to_ell_padded(
-                np.arange(n, dtype=np.int64),
-                row_bucket=n_pad, width_bucket=w_pad)
-            nbr = jnp.asarray(nbr_h.astype(np.int32))
-            wts = jnp.asarray(wts_h)
-        else:
-            nbr, wts = dummy_nbr, dummy_wts
+            dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int32)
+            dummy_wts = jnp.zeros((1, 8), dtype=jnp.float64)
+            if "ell" in (cluster_mode(0, n_pad)[0], refine_mode(0, n_pad)[0]):
+                nbr_h, wts_h, _ = g.to_ell_padded(
+                    np.arange(n, dtype=np.int64),
+                    row_bucket=n_pad, width_bucket=w_pad)
+                nbr = jnp.asarray(nbr_h.astype(np.int32))
+                wts = jnp.asarray(wts_h)
+            else:
+                nbr, wts = dummy_nbr, dummy_wts
 
         # ---- coarsen (level loop on host; arrays stay on device)
         levels: list[tuple] = []
@@ -833,13 +835,15 @@ def multilevel_partition_jax(
                     cur_n, max_cluster_w, cluster if r else None, cw,
                     key_s, order, mode=c_mode)
             if c_key is not None:
-                jax.block_until_ready(cluster)
+                with span("vcycle.sync"):
+                    jax.block_until_ready(cluster)
                 _TUNER.record(c_key, c_mode, time.perf_counter() - t0)
             cw2, cpin2, node_map, nc_dev, ekey = _contract_nodes_j(
                 cur[0], cur[1], cluster, cur[3], cur[4], cur_n)
             es2, ed2, ew2, ne_dev = _contract_edges_j(
                 cur[2], *_sort_j(ekey), n_pad=cur_np)
-            nc = int(nc_dev)
+            with span("vcycle.sync"):
+                nc = int(nc_dev)
             if nc >= cfg.min_shrink * cur_n:
                 break
             levels.append((cur, cur_n, node_map, level))
@@ -848,7 +852,9 @@ def multilevel_partition_jax(
             # with them instead of paying the level-0 padding everywhere.
             # Old sentinels (= old n_pad) stay recognizable: >= the new pad.
             new_np = max(bucket_size(max(nc, p.k)), 64)
-            new_ep = bucket_size(int(ne_dev), minimum=min(8 * new_np, 2048))
+            with span("vcycle.sync"):
+                ne = int(ne_dev)
+            new_ep = bucket_size(ne, minimum=min(8 * new_np, 2048))
             new_ep = min(new_ep, cur_ep)
             if new_np < cur_np or new_ep < cur_ep:
                 es2, ed2, ew2 = es2[:new_ep], ed2[:new_ep], ew2[:new_ep]
@@ -856,8 +862,9 @@ def multilevel_partition_jax(
             cur = (es2, ed2, ew2, cw2, cpin2)
             cur_n = nc
             cur_np, cur_ep = new_np, new_ep
-            cur_free = int(jnp.sum((cpin2 == -1)
-                                   & (jnp.arange(cur_np) < nc)))
+            with span("vcycle.sync"):
+                cur_free = int(jnp.sum((cpin2 == -1)
+                                       & (jnp.arange(cur_np) < nc)))
             level += 1
 
         # ---- initial partition on the coarsest level
@@ -872,7 +879,8 @@ def multilevel_partition_jax(
         else:  # one scalar pull: the coarsest free max degree sizes slices
             cnt = jnp.bincount(jnp.minimum(cur[0], cur_np), length=cur_np + 1)
             free_c = (cur[4] == -1) & (jnp.arange(cur_np) < cur_n)
-            max_deg = max(int(jnp.max(jnp.where(free_c, cnt[:cur_np], 0))), 1)
+            with span("vcycle.sync"):
+                max_deg = max(int(jnp.max(jnp.where(free_c, cnt[:cur_np], 0))), 1)
         # floored at 64: per-step slices stay trivially cheap and batch-to-
         # batch degree noise maps onto one compilation instead of four
         w_c = min(bucket_size(max_deg, minimum=64), cur_ep)
@@ -891,7 +899,8 @@ def multilevel_partition_jax(
             cur[3], cur[4], cur_n, labels, loads, cap,
             rounds=cfg.refine_rounds, mode=r_mode)
         if r_key is not None:
-            jax.block_until_ready((labels, loads))
+            with span("vcycle.sync"):
+                jax.block_until_ready((labels, loads))
             _TUNER.record(r_key, r_mode, time.perf_counter() - t0)
 
         # ---- uncoarsen + refine
@@ -907,8 +916,11 @@ def multilevel_partition_jax(
                 fine[3], fine[4], fine_n, labels, loads, cap,
                 rounds=cfg.refine_rounds, mode=r_mode)
             if r_key is not None:
-                jax.block_until_ready((labels, loads))
+                with span("vcycle.sync"):
+                    jax.block_until_ready((labels, loads))
                 _TUNER.record(r_key, r_mode, time.perf_counter() - t0)
 
         # the single device->host transfer of the batch assignment
-        return np.asarray(labels[:n]).astype(np.int64)
+        with span("vcycle.sync"):
+            out = np.asarray(labels[:n])
+        return out.astype(np.int64)

@@ -56,6 +56,7 @@ from repro.core.batch_model import build_batch_model_from_adj
 from repro.core.multilevel import multilevel_partition_resilient
 from repro.core.metrics import internal_edge_ratio_adj, streaming_cut_increment
 from repro.core.prefetch import PrefetchStream
+from repro.core.spans import span
 from repro.core.checkpoint import (
     Checkpointer,
     check_resume,
@@ -242,57 +243,69 @@ def _buffcut_partition_pipelined(
             if resident > stats.peak_resident_bytes:
                 stats.peak_resident_bytes = resident
 
+    stage_s = stats.stage_s
+
+    def next_task():
+        """T3's next queue item; None once stopped or poison-pilled."""
+        with span("buffcut.t3.task_wait", stage_s):
+            while True:
+                try:
+                    return task_q.get(timeout=_POLL_S)
+                except queue.Empty:
+                    if stop.is_set():
+                        return None
+
     def partition_worker() -> None:  # T3
         try:
             while True:
-                try:
-                    item = task_q.get(timeout=_POLL_S)
-                except queue.Empty:
-                    if stop.is_set():
-                        return
-                    continue
+                item = next_task()
                 if item is None:
                     return
                 kind, payload = item
                 with lock:
                     if kind == "batch":
                         bnodes, degs, nbr_c, w_c, node_w_b = payload
-                        model = build_batch_model_from_adj(
-                            n, bnodes, degs, nbr_c, w_c, node_w_b, block, cfg.k
-                        )
-                        note_peak(
-                            model.graph.indices.nbytes + model.graph.edge_w.nbytes
-                        )
-                        labels = multilevel_partition_resilient(
-                            model.graph, model.pinned_block, p, loads, cfg.ml,
-                            on_fallback=stats.note_engine_fallback,
-                        )
-                        lab_b = labels[: bnodes.shape[0]]
-                        block[bnodes] = lab_b
-                        np.add.at(loads, lab_b, node_w_b.astype(np.float64))
-                        stats.cut_weight += streaming_cut_increment(
-                            bnodes, lab_b, degs, nbr_c, w_c, block
-                        )
-                        stats.n_batches += 1
-                        if cfg.collect_stats:
-                            stats.ier_per_batch.append(
-                                internal_edge_ratio_adj(bnodes, nbr_c, w_c, n)
+                        with span("buffcut.t3.batch_model", stage_s):
+                            model = build_batch_model_from_adj(
+                                n, bnodes, degs, nbr_c, w_c, node_w_b, block, cfg.k
                             )
+                            note_peak(
+                                model.graph.indices.nbytes + model.graph.edge_w.nbytes
+                            )
+                        with span("buffcut.t3.vcycle", stage_s):
+                            labels = multilevel_partition_resilient(
+                                model.graph, model.pinned_block, p, loads, cfg.ml,
+                                on_fallback=stats.note_engine_fallback,
+                            )
+                        stats.ml_time_s = stage_s["buffcut.t3.vcycle"]
+                        with span("buffcut.t3.commit", stage_s):
+                            lab_b = labels[: bnodes.shape[0]]
+                            block[bnodes] = lab_b
+                            np.add.at(loads, lab_b, node_w_b.astype(np.float64))
+                            stats.cut_weight += streaming_cut_increment(
+                                bnodes, lab_b, degs, nbr_c, w_c, block
+                            )
+                            stats.n_batches += 1
+                            if cfg.collect_stats:
+                                stats.ier_per_batch.append(
+                                    internal_edge_ratio_adj(bnodes, nbr_c, w_c, n)
+                                )
                     else:  # single hub task: payload carries the stream record
-                        v, nbrs, nbr_w, node_w = payload
-                        i = fennel_choose(nbrs, nbr_w, float(node_w), block, loads, p)
-                        block[v] = i
-                        loads[i] += np.float32(node_w)
-                        hv = np.array([v], dtype=np.int64)
-                        stats.cut_weight += streaming_cut_increment(
-                            hv,
-                            np.array([i], dtype=np.int64),
-                            np.array([nbrs.size], dtype=np.int64),
-                            nbrs.astype(np.int64),
-                            nbr_w.astype(np.float64),
-                            block,
-                        )
-                        stats.n_hubs += 1
+                        with span("buffcut.t3.hub", stage_s):
+                            v, nbrs, nbr_w, node_w = payload
+                            i = fennel_choose(nbrs, nbr_w, float(node_w), block, loads, p)
+                            block[v] = i
+                            loads[i] += np.float32(node_w)
+                            hv = np.array([v], dtype=np.int64)
+                            stats.cut_weight += streaming_cut_increment(
+                                hv,
+                                np.array([i], dtype=np.int64),
+                                np.array([nbrs.size], dtype=np.int64),
+                                nbrs.astype(np.int64),
+                                nbr_w.astype(np.float64),
+                                block,
+                            )
+                            stats.n_hubs += 1
                 with ilock:
                     inflight["task_bytes"] -= _payload_bytes(payload)
                 with done_cv:
@@ -310,27 +323,32 @@ def _buffcut_partition_pipelined(
     worker.start()
 
     def put_task(item) -> None:
-        while True:
-            check_worker()
-            try:
-                task_q.put(item, timeout=_POLL_S)
-                if item is not None:  # the poison pill is not a task
-                    counts["put"] += 1
-                return
-            except queue.Full:
-                continue
+        check_worker()
+        try:
+            task_q.put_nowait(item)
+        except queue.Full:
+            with span("buffcut.t2.put_wait", stage_s):
+                while True:
+                    try:
+                        task_q.put(item, timeout=_POLL_S)
+                        break
+                    except queue.Full:
+                        check_worker()
+        if item is not None:  # the poison pill is not a task
+            counts["put"] += 1
 
     def flush_batch() -> None:
         if batch:
-            bnodes = np.asarray(batch, dtype=np.int64)
-            nbr_c, w_c, degs = st.adj.slice(bnodes)
-            node_w_b = st.adj.node_weights(bnodes)
-            st.release(bnodes)  # payload is self-contained; cache shrinks now
-            payload = (bnodes, degs, nbr_c, w_c, node_w_b)
-            with ilock:
-                inflight["task_bytes"] += _payload_bytes(payload)
-            put_task(("batch", payload))
-            batch.clear()
+            with span("buffcut.t2.flush", stage_s):
+                bnodes = np.asarray(batch, dtype=np.int64)
+                nbr_c, w_c, degs = st.adj.slice(bnodes)
+                node_w_b = st.adj.node_weights(bnodes)
+                st.release(bnodes)  # payload is self-contained; cache shrinks now
+                payload = (bnodes, degs, nbr_c, w_c, node_w_b)
+                with ilock:
+                    inflight["task_bytes"] += _payload_bytes(payload)
+                put_task(("batch", payload))
+                batch.clear()
 
     def blocks():
         """(records, tokens) blocks: T1 prefetch thread when configured,
@@ -371,44 +389,46 @@ def _buffcut_partition_pipelined(
     try:
         for recs, toks in blocks():
             check_worker()
-            for ri in range(len(recs)):
-                v, nbrs, nbr_w, node_w = recs[ri]
-                st.observe_scalar(v, nbrs, nbr_w, node_w)
-                if nbrs.size > d_max:
-                    payload = (v, nbrs, nbr_w, node_w)
-                    with ilock:
-                        inflight["task_bytes"] += _payload_bytes(payload)
-                    put_task(("hub", payload))
-                    st.bump_assigned_scalar(v, False, fscore, inc)  # enqueued == assigned
-                    adj.drop_one(v)
-                else:
-                    if nss:
-                        st.bump_buffered_scalar(v, fscore, inc)
-                    insert(v, st.score_scalar(v, fscore))
-                    member[v] = True
-                while len(pq) >= buffer_size and len(batch) < batch_size:
-                    u = extract()
-                    member[u] = False
-                    batch.append(u)
-                    st.bump_assigned_scalar(u, True, fscore, inc)
-                    if len(batch) == batch_size:
-                        flush_batch()
-                pos = toks[ri]
-                if pos is not None:
-                    last_pos = pos
-                if (ckpt is not None and last_pos is not None
-                        and ckpt.due(stats.n_batches)):
-                    quiesce()  # drain T3 so the snapshot sees a closed boundary
-                    ckpt.maybe_save(stats.n_batches, make_state)
+            with span("buffcut.t2.block", stage_s):
+                for ri in range(len(recs)):
+                    v, nbrs, nbr_w, node_w = recs[ri]
+                    st.observe_scalar(v, nbrs, nbr_w, node_w)
+                    if nbrs.size > d_max:
+                        payload = (v, nbrs, nbr_w, node_w)
+                        with ilock:
+                            inflight["task_bytes"] += _payload_bytes(payload)
+                        put_task(("hub", payload))
+                        st.bump_assigned_scalar(v, False, fscore, inc)  # enqueued == assigned
+                        adj.drop_one(v)
+                    else:
+                        if nss:
+                            st.bump_buffered_scalar(v, fscore, inc)
+                        insert(v, st.score_scalar(v, fscore))
+                        member[v] = True
+                    while len(pq) >= buffer_size and len(batch) < batch_size:
+                        u = extract()
+                        member[u] = False
+                        batch.append(u)
+                        st.bump_assigned_scalar(u, True, fscore, inc)
+                        if len(batch) == batch_size:
+                            flush_batch()
+                    pos = toks[ri]
+                    if pos is not None:
+                        last_pos = pos
+                    if (ckpt is not None and last_pos is not None
+                            and ckpt.due(stats.n_batches)):
+                        quiesce()  # drain T3 so the snapshot sees a closed boundary
+                        ckpt.maybe_save(stats.n_batches, make_state)
             note_peak()
-        while len(pq) > 0:
-            u = extract()
-            member[u] = False
-            batch.append(u)
-            st.bump_assigned_scalar(u, True, fscore, inc)
-            if len(batch) == batch_size:
-                flush_batch()
-        flush_batch()
+        with span("buffcut.t2.block", stage_s):  # the buffer's final drain
+            while len(pq) > 0:
+                u = extract()
+                member[u] = False
+                batch.append(u)
+                st.bump_assigned_scalar(u, True, fscore, inc)
+                if len(batch) == batch_size:
+                    flush_batch()
+            flush_batch()
         quiesce()
         put_task(None)
         worker.join(timeout=_JOIN_TIMEOUT_S)

@@ -33,12 +33,14 @@ larger values deepen the read-ahead window.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from collections.abc import Iterator
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.graphs.stream import NodeStreamBase
 
 # queue poll granularity: how often a blocked pump/consumer re-checks the
@@ -123,24 +125,23 @@ class PrefetchStream(NodeStreamBase):
         the consumer-side `tell()` is exact)."""
         inner = self._inner
         block_n = self._block
-        recs: list = []
-        toks: list = []
-        nbytes = 0
         try:
-            for rec in records:
-                try:
-                    toks.append(inner.tell())
-                except NotImplementedError:
-                    toks.append(None)
-                recs.append(rec)
-                nbytes += _record_bytes(rec)
-                if len(recs) == block_n:
-                    if not self._put(q, stop, (_BLOCK, recs, toks, nbytes)):
-                        return
-                    recs, toks, nbytes = [], [], 0
-            if recs:
-                if not self._put(q, stop, (_BLOCK, recs, toks, nbytes)):
+            while True:
+                recs: list = []
+                toks: list = []
+                nbytes = 0
+                with span("buffcut.t1.read"):
+                    for rec in itertools.islice(records, block_n):
+                        try:
+                            toks.append(inner.tell())
+                        except NotImplementedError:
+                            toks.append(None)
+                        recs.append(rec)
+                        nbytes += _record_bytes(rec)
+                if recs and not self._put(q, stop, (_BLOCK, recs, toks, nbytes)):
                     return
+                if len(recs) < block_n:
+                    break
             self._put(q, stop, (_DONE, None, None, 0))
         except BaseException as exc:  # noqa: BLE001 — forwarded, not dropped
             self._put(q, stop, (_ERR, exc, None, 0))

@@ -146,6 +146,21 @@ def test_stream_driver_identical_blocks():
     assert st.ml_time_s > 0.0
 
 
+def test_pipelined_driver_times_its_vcycles():
+    """The pipelined driver's partition worker keeps `ml_time_s` as the
+    summed seconds of its V-cycle span, beside the other stage spans."""
+    from repro.api import partition
+
+    g = rmat_graph(768, 8, seed=2)
+    st = partition(g, driver="buffcut-pipe", engine="jax", k=4, buffer_size=128,
+                   batch_size=64, d_max=512.0).stats
+    assert st.n_batches >= 5
+    assert st.ml_time_s > 0.0
+    assert st.ml_time_s == st.stage_s["buffcut.t3.vcycle"]
+    assert {"buffcut.t2.block", "buffcut.t2.flush", "buffcut.t3.task_wait",
+            "buffcut.t3.batch_model", "buffcut.t3.commit"} <= set(st.stage_s)
+
+
 def test_jit_cache_bounded_over_stream():
     """Shape bucketing: a 20-batch stream compiles each engine entry point
     at most 3 times (uniform batches share one padded shape; the trailing
