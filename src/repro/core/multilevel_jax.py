@@ -16,9 +16,10 @@ V-cycle (DESIGN.md §3.5) on device:
   refine    capacity-constrained LP refinement rounds per level.
 
 The fused best-move + greedy capacity acceptance (numpy: lexsort + grouped
-cumsum) becomes an on-device `jnp.lexsort` + segmented `lax.cummax` prefix
-scan.  Neighbor-label aggregation has three modes, picked per level by
-padded volume:
+cumsum) becomes a sort-free bit-serial threshold search per target: over
+a one-hot of the k + 1 block slots in refinement, over n_pad + 1 scatter
+segments for clustering's node-id targets.  Neighbor-label aggregation has
+three modes, picked per level by padded volume:
 
   dense   scatter-add into a dense (n_pad, L_pad) count matrix — the
           device twin of the numpy composite-key bincount,
@@ -88,6 +89,25 @@ def trace_counts() -> dict[str, int]:
 
 def reset_trace_counts() -> None:
     _TRACE_COUNTS.clear()
+
+
+# dispatches per capacity-acceptance path: each refine call (its k + 1
+# block slots) and each clustering round (the node-id scatter)
+_ACCEPT_COUNTS: dict[str, int] = {}
+
+
+def _count_accept(name: str) -> None:
+    _ACCEPT_COUNTS[name] = _ACCEPT_COUNTS.get(name, 0) + 1
+
+
+def accept_counts() -> dict[str, int]:
+    """Dispatched refine calls and clustering rounds per acceptance path
+    (`refine_slots`, `cluster_scatter`) since the last reset."""
+    return dict(_ACCEPT_COUNTS)
+
+
+def reset_accept_counts() -> None:
+    _ACCEPT_COUNTS.clear()
 
 
 def _jit(fn, *, static=(), donate=()):
@@ -160,7 +180,9 @@ def reset_agg_tuner() -> None:
 # op, and it takes minutes over a sort with 64-bit or multi-word keys, a
 # float64 cumsum, or a flat scan over 2^16+ elements.  So indices are int32
 # throughout, every large sort has a single u32 key (up to n_pad = 2^16),
-# prefix counts are matmuls, and per-group reductions are scatters.
+# prefix counts are matmuls, and per-group reductions are scatters, or
+# masked reductions over a one-hot where the groups are few (refine's
+# k + 1 acceptance targets).
 # Weights, loads and gains stay f64 (ROADMAP §1.4 owns that question).
 # --------------------------------------------------------------------------
 
@@ -418,6 +440,8 @@ def _accept_with_capacity(
     node_w: jnp.ndarray,
     capacity: jnp.ndarray,
     n_pad: int,
+    *,
+    slots: bool = False,
 ) -> jnp.ndarray:
     """Per-target gain-descending prefix acceptance, on device, sort-free.
 
@@ -427,23 +451,48 @@ def _accept_with_capacity(
     accepts exactly the movers whose rank key (gain desc, id asc) is at most
     θ_t, the largest key with F_t(θ_t) = w{movers of t keyed <= θ_t} <=
     cap_t.  θ_t is built bit by bit, most significant first, with one
-    segment-sum per bit.  The sums are exact for integer weights, so the
+    per-target sum per bit.  The sums are exact for integer weights, so the
     accepted set is the numpy one wherever the parity contract applies.
+
+    Movers' targets index `capacity`; its entries and one discard slot for
+    non-movers are the target domain.  By default each step gathers θ_t at
+    every row and segment-sums F_t (clustering: n_pad + 1 node ids).
+    `slots` is for a short capacity vector (refine: k + 1 block slots):
+    each step reads θ_t by a one-hot select and forms F_t as a masked
+    reduction over an (n_t + 1, n_pad) one-hot built once, with no scatter or
+    gather on the n_pad rows.
     """
-    tgt = jnp.where(movers, targets, n_pad).astype(_I32)
+    n_t = capacity.shape[0]
+    tgt = jnp.where(movers, targets, n_t).astype(_I32)
     w = jnp.where(movers, node_w, 0.0)
     hi, lo = _desc_words(jnp.where(movers, gains, 0.0))
     words = (hi, lo, jnp.arange(n_pad, dtype=_U32))
-    cap = jnp.concatenate([capacity[:n_pad], jnp.zeros(1, capacity.dtype)])
+    cap = jnp.concatenate([capacity, jnp.zeros(1, capacity.dtype)])
     cap = cap + 1e-9
 
+    if slots:
+        # target slots on the short axis, rows on the long one
+        hot = jnp.arange(n_t + 1, dtype=_I32)[:, None] == tgt[None, :]
+
+        def lookup(table):
+            # one nonzero per column: the sum is the selected entry, exactly
+            return jnp.sum(jnp.where(hot, table[:, None], 0), axis=0,
+                           dtype=table.dtype)
+
+        def per_target(x):
+            return jnp.sum(jnp.where(hot, x[None, :], 0.0), axis=1)
+    else:
+        def lookup(table):
+            return table[tgt]
+
+        def per_target(x):
+            return jax.ops.segment_sum(x, tgt, num_segments=n_t + 1)
+
     def weight_upto(theta):
-        t = [th[tgt] for th in theta]
+        t = [lookup(th) for th in theta]
         le = (words[0] < t[0]) | ((words[0] == t[0]) & (
             (words[1] < t[1]) | ((words[1] == t[1]) & (words[2] <= t[2]))))
-        f = jax.ops.segment_sum(jnp.where(le, w, 0.0), tgt,
-                                num_segments=n_pad + 1)
-        return le, f
+        return le, per_target(jnp.where(le, w, 0.0))
 
     id_bits = max(n_pad - 1, 1).bit_length()
     sched = [(0, b) for b in range(31, -1, -1)] + \
@@ -459,10 +508,10 @@ def _accept_with_capacity(
         ok = weight_upto(cand)[1] <= cap
         return tuple(jnp.where(ok, c, th) for c, th in zip(cand, theta))
 
-    zero = jnp.zeros(n_pad + 1, dtype=_U32)
+    zero = jnp.zeros(n_t + 1, dtype=_U32)
     theta = jax.lax.fori_loop(0, len(sched), step, (zero, zero, zero))
     le, f = weight_upto(theta)
-    return movers & le & (f[tgt] <= cap[tgt])
+    return movers & le & (lookup(f) <= lookup(cap))
 
 
 # --------------------------------------------------------------------------
@@ -498,23 +547,28 @@ def _lp_cluster_round(esrc, edst, ew, nbr, wts, node_w, pinned, n,
     # pinned-owned clusters are never targets; cluster labels are node ids,
     # so the node-domain mask doubles as the label-column mask (l_pad = n_pad)
     forbidden = pinned >= 0
-    if cluster is None:
+    round0 = cluster is None
+    if round0:
         cluster = jnp.arange(n_pad, dtype=_I32)
         cw = jnp.where(valid, node_w, 0.0)
-        _, best_w, best_lab = _agg_round0(esrc, edst, ew, forbidden, n_pad)
-    elif mode == "sort":
-        _, best_w, best_lab = _agg_presorted(
-            esrc, edst, ew, cluster, cluster, forbidden, n_pad, key_s, order)
-    else:
-        _, best_w, best_lab = _aggregate(
-            mode, esrc, edst, ew, nbr, wts, cluster, cluster, forbidden,
-            n_pad, n_pad)
+    with jax.named_scope("lp.aggregate"):
+        if round0:
+            _, best_w, best_lab = _agg_round0(esrc, edst, ew, forbidden, n_pad)
+        elif mode == "sort":
+            _, best_w, best_lab = _agg_presorted(
+                esrc, edst, ew, cluster, cluster, forbidden, n_pad, key_s,
+                order)
+        else:
+            _, best_w, best_lab = _aggregate(
+                mode, esrc, edst, ew, nbr, wts, cluster, cluster, forbidden,
+                n_pad, n_pad)
     movers = free & (best_w > 0.0)
     tgt_c = jnp.clip(best_lab, 0, n_pad - 1)
     movers &= cw[tgt_c] + node_w <= max_cluster_w
     capacity = jnp.maximum(max_cluster_w - cw, 0.0)
-    accept = _accept_with_capacity(movers, best_lab, best_w, node_w,
-                                   capacity, n_pad)
+    with jax.named_scope("lp.accept"):
+        accept = _accept_with_capacity(movers, best_lab, best_w, node_w,
+                                       capacity, n_pad)
     wmv = jnp.where(accept, node_w, 0.0)
     out = jnp.where(accept, best_lab, n_pad)
     src_c = jnp.where(accept, cluster, n_pad)
@@ -661,21 +715,22 @@ def _lp_refine(esrc, edst, ew, nbr, wts, node_w, pinned, n, labels, loads,
 
     def round_(_, state):
         labels, loads = state
-        cur, best_w, best_lab = _aggregate(
-            mode, esrc, edst, ew, nbr, wts, labels, labels, None, n_pad, k)
+        with jax.named_scope("lp.aggregate"):
+            cur, best_w, best_lab = _aggregate(
+                mode, esrc, edst, ew, nbr, wts, labels, labels, None, n_pad, k)
         gains = best_w - cur
         movers = free & (gains > 1e-12)
-        capacity = jnp.zeros(n_pad, dtype=loads.dtype).at[:k].set(
-            jnp.maximum(cap - loads, 0.0))
-        accept = _accept_with_capacity(movers, best_lab, gains, node_w,
-                                       capacity, n_pad)
-        wmv = jnp.where(accept, node_w, 0.0)
-        old = jnp.where(accept, labels, k)
-        new = jnp.where(accept, best_lab, k)
+        room = jnp.maximum(cap - loads, 0.0)
+        with jax.named_scope("lp.accept"):
+            acc = _accept_with_capacity(movers, best_lab, gains, node_w,
+                                        room, n_pad, slots=True)
+        wmv = jnp.where(acc, node_w, 0.0)
+        old = jnp.where(acc, labels, k)
+        new = jnp.where(acc, best_lab, k)
         loads = (loads
                  - jax.ops.segment_sum(wmv, old, num_segments=k + 1)[:k]
                  + jax.ops.segment_sum(wmv, new, num_segments=k + 1)[:k])
-        labels = jnp.where(accept, best_lab, labels)
+        labels = jnp.where(acc, best_lab, labels)
         return labels, loads
 
     return jax.lax.fori_loop(0, rounds, round_, (labels, loads))
@@ -744,9 +799,8 @@ def multilevel_partition_jax(
     with jax.enable_x64(True):
         with span("vcycle.pack"):
             n = g.n
-            # floored at the block count: refine's capacity vector and accept's
-            # target domain live in node-padded arrays, so n_pad must cover k
-            # even when the graph is smaller than the partition (k > n)
+            # floored at the block count, so n_pad covers k even when the
+            # graph is smaller than the partition (k > n)
             n_pad = bucket_size(max(n, p.k))
             # edge bucket floored at 8·n_pad for stream-scale graphs: batch
             # models in one stream have near-constant node counts but noisy
@@ -834,6 +888,7 @@ def multilevel_partition_jax(
                     cur[0], cur[1], cur[2], lvl_nbr, lvl_wts, cur[3], cur[4],
                     cur_n, max_cluster_w, cluster if r else None, cw,
                     key_s, order, mode=c_mode)
+                _count_accept("cluster_scatter")
             if c_key is not None:
                 with span("vcycle.sync"):
                     jax.block_until_ready(cluster)
@@ -898,6 +953,7 @@ def multilevel_partition_jax(
             wts if level == 0 else dummy_wts,
             cur[3], cur[4], cur_n, labels, loads, cap,
             rounds=cfg.refine_rounds, mode=r_mode)
+        _count_accept("refine_slots")
         if r_key is not None:
             with span("vcycle.sync"):
                 jax.block_until_ready((labels, loads))
@@ -915,6 +971,7 @@ def multilevel_partition_jax(
                 wts if lvl == 0 else dummy_wts,
                 fine[3], fine[4], fine_n, labels, loads, cap,
                 rounds=cfg.refine_rounds, mode=r_mode)
+            _count_accept("refine_slots")
             if r_key is not None:
                 with span("vcycle.sync"):
                     jax.block_until_ready((labels, loads))

@@ -13,7 +13,11 @@ import repro.core.multilevel_jax as mlj
 from repro.core import BuffCutConfig
 from repro.core.batch_model import build_batch_model
 from repro.core.fennel import FennelParams
-from repro.core.multilevel import MultilevelConfig, multilevel_partition
+from repro.core.multilevel import (
+    MultilevelConfig,
+    _accept_with_capacity,
+    multilevel_partition,
+)
 from repro.core.vector_stream import buffcut_partition_vectorized
 from repro.graphs import (
     apply_order,
@@ -124,6 +128,67 @@ def test_jax_k_exceeds_node_bucket():
     assert np.array_equal(ref, got)
 
 
+# ------------------------------------------- capacity acceptance paths
+
+def _accept_case(case: str, k: int, n_pad: int):
+    """Movers, targets, gains, integer node weights and per-block
+    capacities for one acceptance case."""
+    rng = np.random.default_rng([k, n_pad, len(case)])
+    movers = rng.random(n_pad) < 0.7
+    targets = rng.integers(0, k, n_pad)
+    node_w = rng.integers(1, 6, n_pad).astype(np.float64)
+    gains = rng.integers(1, 64, n_pad).astype(np.float64)
+    per_block = np.bincount(targets[movers], weights=node_w[movers],
+                            minlength=k)
+    capacity = np.floor(per_block * rng.uniform(0.2, 0.8, k))
+    if case == "slack":
+        capacity = per_block + rng.integers(0, 3, k)
+    elif case == "ties":
+        gains[:] = 3.0  # the id order alone decides
+    elif case == "wide":
+        # 48 significant bits, most of them below f32 precision
+        gains = (rng.integers(1 << 47, 1 << 48, n_pad) * 2.0 ** -40)
+        gains[1::3] = gains[::3][: gains[1::3].size]  # some exact ties
+    elif case == "idle":
+        movers &= rng.random(n_pad) < 0.3
+        capacity[rng.random(k) < 0.4] = 0.0
+    gains[~movers] = rng.normal(size=int((~movers).sum()))  # never read
+    return movers, targets, gains, node_w, capacity
+
+
+@pytest.mark.parametrize("case", ["binding", "slack", "ties", "wide", "idle"])
+@pytest.mark.parametrize("n_pad", [64, 1024])
+@pytest.mark.parametrize("k", [2, 8, 32])
+def test_accept_dense_slots_match_scatter_and_oracle(k, n_pad, case):
+    """Refine's acceptance over its k + 1 target slots accepts the same
+    movers as clustering's n_pad-segment scatter path and as the numpy
+    lexsort + grouped cumsum, on binding and slack capacities, equal gains
+    (id tie-break), 48-bit gains, non-movers and zero-capacity blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    movers, targets, gains, node_w, capacity = _accept_case(case, k, n_pad)
+    ids = np.nonzero(movers)[0]
+    want = np.zeros(n_pad, dtype=bool)
+    want[ids] = _accept_with_capacity(ids, targets[ids], gains[ids], node_w,
+                                      capacity)
+    padded = np.zeros(n_pad)
+    padded[:k] = capacity
+    with jax.enable_x64(True):
+        args = [jnp.asarray(a) for a in (movers, targets.astype(np.int32),
+                                         gains, node_w)]
+        slots = np.asarray(mlj._accept_with_capacity(
+            *args, jnp.asarray(capacity), n_pad, slots=True))
+        scatter = np.asarray(mlj._accept_with_capacity(
+            *args, jnp.asarray(padded), n_pad))
+    assert np.array_equal(slots, want)
+    assert np.array_equal(scatter, want)
+    if case == "slack":
+        assert np.array_equal(want, movers)
+    elif case != "idle":
+        assert 0 < want.sum() < movers.sum()  # the capacities bind
+
+
 # ------------------------------------------------- stream-level contract
 
 def _stream_cfg(engine, k=4, batch=64):
@@ -178,6 +243,27 @@ def test_jit_cache_bounded_over_stream():
     counts = mlj.trace_counts()
     assert counts, "engine never traced — did the jax engine run?"
     assert max(counts.values()) <= 3, counts
+
+
+def test_stream_accept_counts():
+    """A stream whose batches coarsen: every refine call takes the k + 1
+    block slots, every clustering round (node-id targets) the scatter, and
+    the blocks are the sparse engine's."""
+    def cfg(engine):
+        return BuffCutConfig(
+            k=4, buffer_size=512, batch_size=256, d_max=512.0,
+            ml=MultilevelConfig(engine=engine, coarsen_target=32))
+
+    g = rmat_graph(1024, 8, seed=5)
+    b_sp, _ = buffcut_partition_vectorized(g, cfg("sparse"), wave=8, chunk=8)
+    mlj.reset_accept_counts()
+    b_jx, stats = buffcut_partition_vectorized(g, cfg("jax"), wave=8, chunk=8)
+    assert np.array_equal(b_sp, b_jx)
+    acc = mlj.accept_counts()
+    assert set(acc) == {"refine_slots", "cluster_scatter"}, acc
+    # one refine call per level and a clustering round per lp_iters each
+    assert acc["refine_slots"] > stats.n_batches
+    assert acc["cluster_scatter"] >= 2 * (acc["refine_slots"] - stats.n_batches)
 
 
 def test_agg_autotune_identical_labels_and_converges():

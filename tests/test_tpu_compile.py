@@ -93,7 +93,8 @@ def test_fennel_gain_compiles(one_chip, no_cache, rows, width, x64):
 def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width):
     """The refine stage of a δ = 32,768 batch at level 0: the ELL mode runs
     the Pallas histogram inside the stage, dense the scatter twin; both
-    with the sort-free capacity acceptance, all under x64."""
+    with the sort-free capacity acceptance over the k + 1 block slots, all
+    under x64."""
     import repro.core.multilevel_jax as mlj
 
     # the engine turns the kernels on from the backend it runs on; the
