@@ -91,23 +91,26 @@ def reset_trace_counts() -> None:
     _TRACE_COUNTS.clear()
 
 
-# dispatches per capacity-acceptance path: each refine call (its k + 1
-# block slots) and each clustering round (the node-id scatter)
-_ACCEPT_COUNTS: dict[str, int] = {}
+# dispatches per aggregation mode: each refine call (`refine.<mode>`, its
+# acceptance over the k + 1 block slots) and each clustering round
+# (`cluster.<mode>`, its acceptance over the node-id scatter)
+_AGG_COUNTS: dict[str, int] = {}
 
 
-def _count_accept(name: str) -> None:
-    _ACCEPT_COUNTS[name] = _ACCEPT_COUNTS.get(name, 0) + 1
+def _count_agg(name: str) -> None:
+    _AGG_COUNTS[name] = _AGG_COUNTS.get(name, 0) + 1
 
 
-def accept_counts() -> dict[str, int]:
-    """Dispatched refine calls and clustering rounds per acceptance path
-    (`refine_slots`, `cluster_scatter`) since the last reset."""
-    return dict(_ACCEPT_COUNTS)
+def agg_counts() -> dict[str, int]:
+    """Dispatched refine calls and clustering rounds per aggregation mode
+    (`refine.ell`, `refine.dense`, `refine.sort`, `cluster.dense`,
+    `cluster.sort`, and `cluster.ell` where a level 0 of at most 4,096
+    padded nodes clusters on its tiles) since the last reset."""
+    return dict(_AGG_COUNTS)
 
 
-def reset_accept_counts() -> None:
-    _ACCEPT_COUNTS.clear()
+def reset_agg_counts() -> None:
+    _AGG_COUNTS.clear()
 
 
 def _jit(fn, *, static=(), donate=()):
@@ -855,11 +858,12 @@ def multilevel_partition_jax(
             dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int32)
             dummy_wts = jnp.zeros((1, 8), dtype=jnp.float64)
             if "ell" in (cluster_mode(0, n_pad)[0], refine_mode(0, n_pad)[0]):
-                nbr_h, wts_h, _ = g.to_ell_padded(
-                    np.arange(n, dtype=np.int64),
-                    row_bucket=n_pad, width_bucket=w_pad)
-                nbr = jnp.asarray(nbr_h.astype(np.int32))
-                wts = jnp.asarray(wts_h)
+                with span("vcycle.pack_ell"):
+                    nbr_h, wts_h, _ = g.to_ell_padded(
+                        np.arange(n, dtype=np.int64),
+                        row_bucket=n_pad, width_bucket=w_pad)
+                    nbr = jnp.asarray(nbr_h.astype(np.int32))
+                    wts = jnp.asarray(wts_h)
             else:
                 nbr, wts = dummy_nbr, dummy_wts
 
@@ -888,7 +892,7 @@ def multilevel_partition_jax(
                     cur[0], cur[1], cur[2], lvl_nbr, lvl_wts, cur[3], cur[4],
                     cur_n, max_cluster_w, cluster if r else None, cw,
                     key_s, order, mode=c_mode)
-                _count_accept("cluster_scatter")
+                _count_agg(f"cluster.{c_mode}")
             if c_key is not None:
                 with span("vcycle.sync"):
                     jax.block_until_ready(cluster)
@@ -953,7 +957,7 @@ def multilevel_partition_jax(
             wts if level == 0 else dummy_wts,
             cur[3], cur[4], cur_n, labels, loads, cap,
             rounds=cfg.refine_rounds, mode=r_mode)
-        _count_accept("refine_slots")
+        _count_agg(f"refine.{r_mode}")
         if r_key is not None:
             with span("vcycle.sync"):
                 jax.block_until_ready((labels, loads))
@@ -971,7 +975,7 @@ def multilevel_partition_jax(
                 wts if lvl == 0 else dummy_wts,
                 fine[3], fine[4], fine_n, labels, loads, cap,
                 rounds=cfg.refine_rounds, mode=r_mode)
-            _count_accept("refine_slots")
+            _count_agg(f"refine.{r_mode}")
             if r_key is not None:
                 with span("vcycle.sync"):
                     jax.block_until_ready((labels, loads))

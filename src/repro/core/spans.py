@@ -33,6 +33,13 @@ under the same name):
   vcycle.sync            the jax engine's host waits on one device->host
                          pull: a level's scalars, the coarsest degree, an
                          autotune timing, the labels back
+
+These are the stages, `SPANS`.  A span nested inside a stage is not a
+stage of its own:
+
+  vcycle.pack_ell        inside vcycle.pack: the jax engine packs the
+                         level-0 ELL tiles and copies them up, in a batch
+                         whose level 0 aggregates through them
 """
 from __future__ import annotations
 

@@ -15,7 +15,8 @@ RNG = np.random.default_rng(0)
 # ------------------------------------------------------------ histogram
 
 @pytest.mark.parametrize("b,w,k", [(1, 1, 2), (7, 13, 4), (64, 32, 16),
-                                   (130, 7, 32), (100, 64, 256),
+                                   (130, 7, 32), (256, 8, 32), (256, 32, 32),  # mesh tiles
+                                   (100, 64, 256),
                                    (64, 16, 1000)])  # k > MAX_KC: 2-D grid
 def test_histogram_shapes(b, w, k):
     blk = RNG.integers(-1, k, (b, w)).astype(np.int32)

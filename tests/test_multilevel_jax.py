@@ -22,6 +22,7 @@ from repro.core.vector_stream import buffcut_partition_vectorized
 from repro.graphs import (
     apply_order,
     bfs_order,
+    grid_mesh_graph,
     konect_order,
     rmat_graph,
     source_order,
@@ -246,9 +247,10 @@ def test_jit_cache_bounded_over_stream():
 
 
 def test_stream_accept_counts():
-    """A stream whose batches coarsen: every refine call takes the k + 1
-    block slots, every clustering round (node-id targets) the scatter, and
-    the blocks are the sparse engine's."""
+    """A stream whose batches coarsen: `agg_counts` counts a refine call a
+    level and `lp_iters` clustering rounds a coarsened level, under the
+    `refine.` and `cluster.` keys alone, and the blocks are the sparse
+    engine's."""
     def cfg(engine):
         return BuffCutConfig(
             k=4, buffer_size=512, batch_size=256, d_max=512.0,
@@ -256,14 +258,80 @@ def test_stream_accept_counts():
 
     g = rmat_graph(1024, 8, seed=5)
     b_sp, _ = buffcut_partition_vectorized(g, cfg("sparse"), wave=8, chunk=8)
-    mlj.reset_accept_counts()
+    mlj.reset_agg_counts()
     b_jx, stats = buffcut_partition_vectorized(g, cfg("jax"), wave=8, chunk=8)
     assert np.array_equal(b_sp, b_jx)
-    acc = mlj.accept_counts()
-    assert set(acc) == {"refine_slots", "cluster_scatter"}, acc
+    agg = mlj.agg_counts()
+    refine = sum(v for key, v in agg.items() if key.startswith("refine."))
+    cluster = sum(v for key, v in agg.items() if key.startswith("cluster."))
+    assert refine + cluster == sum(agg.values()), agg
     # one refine call per level and a clustering round per lp_iters each
-    assert acc["refine_slots"] > stats.n_batches
-    assert acc["cluster_scatter"] >= 2 * (acc["refine_slots"] - stats.n_batches)
+    assert refine > stats.n_batches
+    assert cluster >= 2 * (refine - stats.n_batches)
+
+
+def _mesh_vcycles(calls: int) -> None:
+    """`calls` V-cycles of the jax engine over successive 256-node row
+    batches of a triangulated 32 x 32 grid, each committed before the next
+    (so later batches carry pinned aux nodes), coarsening to 32 free nodes."""
+    g = grid_mesh_graph(32)
+    k = 4
+    p = _params(g, k)
+    block = np.full(g.n, -1, dtype=np.int64)
+    cfg = MultilevelConfig(engine="jax", coarsen_target=32)
+    for b in range(calls):
+        batch = np.arange(b * 256, (b + 1) * 256)
+        model = build_batch_model(g, batch, block, k)
+        loads = np.bincount(block[block >= 0], minlength=k).astype(np.float64)
+        out = multilevel_partition(model.graph, model.pinned_block, p, loads, cfg)
+        block[batch] = out[:batch.size]
+
+
+@pytest.mark.parametrize("mode", ["ell", "dense"])
+def test_agg_counts_level0_refine(mode, monkeypatch):
+    """`refine.ell` counts the level-0 refine calls, one a V-cycle, where
+    the ELL tiles are taken, and nothing where they are not; the coarse
+    levels' refine calls and every clustering round count under the mode
+    they ran in."""
+    monkeypatch.setattr(mlj, "MODE_OVERRIDE", mode)
+    mlj.reset_agg_counts()
+    _mesh_vcycles(3)
+    agg = mlj.agg_counts()
+    assert agg.get("refine.ell", 0) == (3 if mode == "ell" else 0)
+    assert agg["refine.dense"] > 0  # the coarse levels' refine calls
+    assert agg[f"cluster.{mode}"] > 0  # level 0's clustering rounds
+    assert set(agg) <= {f"{phase}.{m}" for phase in ("refine", "cluster")
+                        for m in ("ell", "dense", "sort")}
+
+
+def test_reset_agg_counts():
+    mlj._count_agg("refine.ell")
+    assert mlj.agg_counts()["refine.ell"] > 0
+    mlj.reset_agg_counts()
+    assert mlj.agg_counts() == {}
+
+
+@pytest.mark.parametrize("mode", ["ell", "dense"])
+def test_pack_ell_span_once_per_batch(mode, monkeypatch):
+    """`vcycle.pack_ell` is entered once a V-cycle, inside `vcycle.pack`,
+    where the ELL tiles are packed, and never where they are not."""
+    entered = []
+    real = mlj.span
+
+    class recording(real):
+        def __enter__(self):
+            entered.append(self._name)
+            return super().__enter__()
+
+    monkeypatch.setattr(mlj, "MODE_OVERRIDE", mode)
+    monkeypatch.setattr(mlj, "span", recording)
+    _mesh_vcycles(3)
+    assert entered.count("vcycle.pack") == 3
+    assert entered.count("vcycle.pack_ell") == (3 if mode == "ell" else 0)
+    if mode == "ell":
+        packs = [i for i, name in enumerate(entered) if name == "vcycle.pack"]
+        ells = [i for i, name in enumerate(entered) if name == "vcycle.pack_ell"]
+        assert all(a < b for a, b in zip(packs, ells))
 
 
 def test_agg_autotune_identical_labels_and_converges():

@@ -89,8 +89,15 @@ def test_fennel_gain_compiles(one_chip, no_cache, rows, width, x64):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("mode,width", [("ell", 256), ("dense", 8)])
-def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width):
+@pytest.mark.parametrize("mode,width,e_pad", [
+    pytest.param("ell", 256, E_PAD, id="ell-256"),
+    # mesh batches, 2^19 edge slots: a structured grid's (degree at most
+    # 6) and a Delaunay mesh's (free rows up to 32 wide)
+    pytest.param("ell", 8, E_PAD // 2, id="ell-8-mesh"),
+    pytest.param("ell", 32, E_PAD // 2, id="ell-32-mesh"),
+    pytest.param("dense", 8, E_PAD, id="dense-8"),
+])
+def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width, e_pad):
     """The refine stage of a δ = 32,768 batch at level 0: the ELL mode runs
     the Pallas histogram inside the stage, dense the scatter twin; both
     with the sort-free capacity acceptance over the k + 1 block slots, all
@@ -108,7 +115,7 @@ def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width):
         compiled = jax.jit(
             mlj._lp_refine, static_argnames=("rounds", "mode"),
         ).lower(
-            s((E_PAD,), i32), s((E_PAD,), i32), s((E_PAD,), f64),
+            s((e_pad,), i32), s((e_pad,), i32), s((e_pad,), f64),
             s((ROWS, width), i32), s((ROWS, width), f64),
             s((ROWS,), f64), s((ROWS,), i32), s((), i32),
             s((ROWS,), i32), s((K,), f64), s((), f64),
