@@ -16,10 +16,12 @@ V-cycle (DESIGN.md §3.5) on device:
   refine    capacity-constrained LP refinement rounds per level.
 
 The fused best-move + greedy capacity acceptance (numpy: lexsort + grouped
-cumsum) becomes a sort-free bit-serial threshold search per target: over
-a one-hot of the k + 1 block slots in refinement, over n_pad + 1 scatter
-segments for clustering's node-id targets.  Neighbor-label aggregation has
-three modes, picked per level by padded volume:
+cumsum) takes two forms by target domain: refinement's k + 1 block slots
+run a sort-free bit-serial threshold search over a one-hot of the slots;
+clustering's node-id targets keep the numpy formulation, one sorted order
+built from single-u32-key sorts and a segmented prefix sum.
+Neighbor-label aggregation has three modes, picked per level by padded
+volume:
 
   dense   scatter-add into a dense (n_pad, L_pad) count matrix — the
           device twin of the numpy composite-key bincount,
@@ -93,7 +95,8 @@ def reset_trace_counts() -> None:
 
 # dispatches per aggregation mode: each refine call (`refine.<mode>`, its
 # acceptance over the k + 1 block slots) and each clustering round
-# (`cluster.<mode>`, its acceptance over the node-id scatter)
+# (`cluster.<mode>`); `accept.sorted` counts the clustering rounds'
+# sorted acceptances
 _AGG_COUNTS: dict[str, int] = {}
 
 
@@ -105,7 +108,8 @@ def agg_counts() -> dict[str, int]:
     """Dispatched refine calls and clustering rounds per aggregation mode
     (`refine.ell`, `refine.dense`, `refine.sort`, `cluster.dense`,
     `cluster.sort`, and `cluster.ell` where a level 0 of at most 4,096
-    padded nodes clusters on its tiles) since the last reset."""
+    padded nodes clusters on its tiles), and clustering rounds whose
+    acceptance sorts (`accept.sorted`), since the last reset."""
     return dict(_AGG_COUNTS)
 
 
@@ -183,9 +187,9 @@ def reset_agg_tuner() -> None:
 # op, and it takes minutes over a sort with 64-bit or multi-word keys, a
 # float64 cumsum, or a flat scan over 2^16+ elements.  So indices are int32
 # throughout, every large sort has a single u32 key (up to n_pad = 2^16),
-# prefix counts are matmuls, and per-group reductions are scatters, or
-# masked reductions over a one-hot where the groups are few (refine's
-# k + 1 acceptance targets).
+# prefix counts are matmuls, f64 prefix sums are unrolled shift-and-add
+# steps, and per-group reductions are scatters, or masked reductions over
+# a one-hot where the groups are few (refine's k + 1 acceptance targets).
 # Weights, loads and gains stay f64 (ROADMAP §1.4 owns that question).
 # --------------------------------------------------------------------------
 
@@ -212,15 +216,22 @@ def _prefix_count(x: jnp.ndarray) -> jnp.ndarray:
     return (rows + carry[:, None]).reshape(n)
 
 
+def _dense_rank(key_s: jnp.ndarray) -> jnp.ndarray:
+    """Rank of each entry of a sorted key vector among its distinct keys."""
+    new = jnp.concatenate([jnp.ones(1, bool), key_s[1:] != key_s[:-1]])
+    return _prefix_count(new.astype(_I32)) - 1
+
+
 def _pair_key(a: jnp.ndarray, b: jnp.ndarray, base: int,
               valid: jnp.ndarray) -> jnp.ndarray:
     """Unsigned key a·base + b for a, b < base; the dtype's max (the
     sentinel, sorting behind every valid key) where invalid.  u32 up to
     base = 2^16, u64 beyond (a slow sort to compile on the TPU).
 
-    At base = 2^16 the valid key (base-1, base-1) equals the sentinel; both
-    callers make that harmless (contract drops self loops, aggregation
-    entries behind the sentinel carry zero weight)."""
+    At base = 2^16 the valid key (base-1, base-1) equals the sentinel; every
+    caller makes that harmless (contract drops self loops, aggregation
+    entries behind the sentinel carry zero weight, and clustering's
+    acceptance bounds its groups by the targets, not by the keys)."""
     dt = _U32 if base <= 1 << 16 else jnp.uint64
     key = a.astype(dt) * dt(base) + b.astype(dt)
     return jnp.where(valid, key, _sentinel(key))
@@ -436,17 +447,16 @@ def _aggregate(
 # fused greedy capacity acceptance (numpy: lexsort + grouped cumsum)
 # --------------------------------------------------------------------------
 
-def _accept_with_capacity(
+def _accept_slots(
     movers: jnp.ndarray,
     targets: jnp.ndarray,
     gains: jnp.ndarray,
     node_w: jnp.ndarray,
     capacity: jnp.ndarray,
     n_pad: int,
-    *,
-    slots: bool = False,
 ) -> jnp.ndarray:
-    """Per-target gain-descending prefix acceptance, on device, sort-free.
+    """Refine's per-target gain-descending prefix acceptance over its k + 1
+    block slots, on device, sort-free.
 
     The numpy engine orders movers by (target, gain desc, id asc) and takes
     each while its target's running weight, itself included, fits the
@@ -457,13 +467,10 @@ def _accept_with_capacity(
     per-target sum per bit.  The sums are exact for integer weights, so the
     accepted set is the numpy one wherever the parity contract applies.
 
-    Movers' targets index `capacity`; its entries and one discard slot for
-    non-movers are the target domain.  By default each step gathers θ_t at
-    every row and segment-sums F_t (clustering: n_pad + 1 node ids).
-    `slots` is for a short capacity vector (refine: k + 1 block slots):
-    each step reads θ_t by a one-hot select and forms F_t as a masked
-    reduction over an (n_t + 1, n_pad) one-hot built once, with no scatter or
-    gather on the n_pad rows.
+    Movers' targets index `capacity` (the blocks); with one discard slot
+    for non-movers they form an (n_t + 1, n_pad) one-hot built once.  Each
+    step reads θ_t at every row by a one-hot select and forms F_t as a
+    masked reduction over it, with no scatter or gather on the n_pad rows.
     """
     n_t = capacity.shape[0]
     tgt = jnp.where(movers, targets, n_t).astype(_I32)
@@ -472,24 +479,16 @@ def _accept_with_capacity(
     words = (hi, lo, jnp.arange(n_pad, dtype=_U32))
     cap = jnp.concatenate([capacity, jnp.zeros(1, capacity.dtype)])
     cap = cap + 1e-9
+    # target slots on the short axis, rows on the long one
+    hot = jnp.arange(n_t + 1, dtype=_I32)[:, None] == tgt[None, :]
 
-    if slots:
-        # target slots on the short axis, rows on the long one
-        hot = jnp.arange(n_t + 1, dtype=_I32)[:, None] == tgt[None, :]
+    def lookup(table):
+        # one nonzero per column: the sum is the selected entry, exactly
+        return jnp.sum(jnp.where(hot, table[:, None], 0), axis=0,
+                       dtype=table.dtype)
 
-        def lookup(table):
-            # one nonzero per column: the sum is the selected entry, exactly
-            return jnp.sum(jnp.where(hot, table[:, None], 0), axis=0,
-                           dtype=table.dtype)
-
-        def per_target(x):
-            return jnp.sum(jnp.where(hot, x[None, :], 0.0), axis=1)
-    else:
-        def lookup(table):
-            return table[tgt]
-
-        def per_target(x):
-            return jax.ops.segment_sum(x, tgt, num_segments=n_t + 1)
+    def per_target(x):
+        return jnp.sum(jnp.where(hot, x[None, :], 0.0), axis=1)
 
     def weight_upto(theta):
         t = [lookup(th) for th in theta]
@@ -517,6 +516,104 @@ def _accept_with_capacity(
     return movers & le & (lookup(f) <= lookup(cap))
 
 
+# Clustering's targets are node ids, where a one-hot would be n_pad^2
+# cells, so its acceptance keeps the numpy formulation: the movers in
+# (target asc, gain desc, id asc) order, a per-target running weight, and
+# every mover whose running weight fits.  The order comes from five sorts
+# through the shared `_sort_j`, each of a single u32 key (u64 above n_pad
+# 2^16, as `_pair_key`) built from dense ranks of the sort before it:
+#
+#   sort  key                          its dense rank
+#   1     gain's lo word (desc)        rank_lo
+#   2     gain's hi word (desc)        rank_hi
+#   3     (rank_hi, rank_lo)           the gain's rank
+#   4     (target, gain rank)          the (target, gain) group's rank
+#   5     (group rank, id)             none: every key is distinct
+#
+# Each sort runs over the order the one before it left, so the small
+# programs between them gather what they need (`elem`: the node at each
+# position) and only the accepted mask is scattered back to node order.
+# Non-movers take the sentinel key in sorts 1-4 and sort behind every
+# mover.  A mover's pair key in sorts 3 and 4 equals the sentinel only at
+# n_pad = 2^16 with both parts 2^16 - 1; its second part is a rank among
+# the movers' keys, which reaches n_pad - 1 only where every row moves, and
+# then no sentinel is present.  The last program bounds the groups by the
+# targets themselves, not by keys.
+
+def _accept_keys(movers, targets, gains):
+    """Clustering acceptance's inputs: each row's target (n_pad where it
+    does not move) and sort 1's and sort 2's keys, the lo and hi words of
+    its gain (`_desc_words`), the sentinel where it does not move."""
+    n_pad = movers.shape[0]
+    hi, lo = _desc_words(jnp.where(movers, gains, 0.0))
+    sent = _sentinel(hi)
+    return (jnp.where(movers, targets, n_pad).astype(_I32),
+            jnp.where(movers, lo, sent), jnp.where(movers, hi, sent))
+
+
+def _accept_hi_key(key_s, order, key_hi):
+    """After sort 1: rank_lo, and sort 2's key in sort 1's order."""
+    _count_trace("accept_hi_key")
+    return _dense_rank(key_s), key_hi[order]
+
+
+def _accept_gain_key(key_s, order, elem, rank_lo, tgt):
+    """After sort 2: sort 3's key, (rank_hi, rank_lo)."""
+    _count_trace("accept_gain_key")
+    n_pad = tgt.shape[0]
+    elem = elem[order]
+    return elem, _pair_key(_dense_rank(key_s), rank_lo[order], n_pad,
+                           tgt[elem] < n_pad)
+
+
+def _accept_target_key(key_s, order, elem, tgt):
+    """After sort 3: sort 4's key, (target, gain rank)."""
+    _count_trace("accept_target_key")
+    n_pad = tgt.shape[0]
+    elem = elem[order]
+    t = tgt[elem]
+    return elem, _pair_key(t, _dense_rank(key_s), n_pad, t < n_pad)
+
+
+def _accept_id_key(key_s, order, elem):
+    """After sort 4: sort 5's key, (group rank, id)."""
+    _count_trace("accept_id_key")
+    elem = elem[order]
+    return elem, _pair_key(_dense_rank(key_s), elem, elem.shape[0], True)
+
+
+def _segmented_cumsum(x: jnp.ndarray, head: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of `x` restarting at every `head` row, in
+    log2(n) shift-and-add steps of elementwise ops (Hillis-Steele): the
+    TPU compiler takes minutes over an f64 cumsum or scan.  Exact where
+    every partial sum is (integer weights)."""
+    n = x.shape[0]
+    d = 1
+    while d < n:
+        x = jnp.where(head, x,
+                      x + jnp.concatenate([jnp.zeros(d, x.dtype), x[:-d]]))
+        head = head | jnp.concatenate([jnp.ones(d, bool), head[:-d]])
+        d *= 2
+    return x
+
+
+def _accept_prefix(order, elem, tgt, node_w, capacity):
+    """The acceptance along the sorted order (row i is node
+    elem[order[i]]): each mover whose target's running weight, itself
+    included, fits the target's capacity.  Returns the mask in node
+    order."""
+    n_pad = tgt.shape[0]
+    elem = elem[order]
+    t_s = tgt[elem]
+    moving = t_s < n_pad
+    head = jnp.concatenate([jnp.ones(1, bool), t_s[1:] != t_s[:-1]])
+    within = _segmented_cumsum(jnp.where(moving, node_w[elem], 0.0), head)
+    ok = moving & (within <= capacity[jnp.minimum(t_s, n_pad - 1)] + 1e-9)
+    # an int32 scatter: a bool one takes the TPU compiler seconds
+    return jnp.zeros(n_pad, _I32).at[elem].set(ok.astype(_I32),
+                                               unique_indices=True) > 0
+
+
 # --------------------------------------------------------------------------
 # jitted V-cycle stages
 # --------------------------------------------------------------------------
@@ -540,9 +637,12 @@ def _cluster_key(esrc, edst, cluster):
 
 def _lp_cluster_round(esrc, edst, ew, nbr, wts, node_w, pinned, n,
                       max_cluster_w, cluster, cw, key_s, order, *, mode: str):
-    """One size-constrained LP clustering round.  `cluster` None is round
-    0: labels are all distinct, and the sort-free all-distinct fast path
-    aggregates; later rounds use `mode` (sort: over the presorted keys)."""
+    """One size-constrained LP clustering round up to its acceptance: each
+    node's best cluster, the movers, and the acceptance's sort keys.
+    `cluster` None is round 0: labels are all distinct, and the sort-free
+    all-distinct fast path aggregates; later rounds use `mode` (sort: over
+    the presorted keys).  Returns the cluster weights (made here in round
+    0), the targets and the first two sort keys (`_accept_keys`)."""
     _count_trace("lp_cluster" if cluster is not None else "lp_cluster0")
     n_pad = node_w.shape[0]
     valid = jnp.arange(n_pad, dtype=_I32) < n
@@ -568,17 +668,41 @@ def _lp_cluster_round(esrc, edst, ew, nbr, wts, node_w, pinned, n,
     movers = free & (best_w > 0.0)
     tgt_c = jnp.clip(best_lab, 0, n_pad - 1)
     movers &= cw[tgt_c] + node_w <= max_cluster_w
-    capacity = jnp.maximum(max_cluster_w - cw, 0.0)
     with jax.named_scope("lp.accept"):
-        accept = _accept_with_capacity(movers, best_lab, best_w, node_w,
-                                       capacity, n_pad)
+        return (cw, *_accept_keys(movers, best_lab, best_w))
+
+
+def _acceptance_order(tgt, key_lo, key_hi):
+    """Clustering acceptance's order: the five sorts and the programs
+    between them, dispatched from the host.  Returns (order, elem): the
+    order's row i is node elem[order[i]]."""
+    key_s, order = _sort_j(key_lo)
+    rank_lo, key = _accept_hi_key_j(key_s, order, key_hi)
+    elem = order
+    key_s, order = _sort_j(key)
+    elem, key = _accept_gain_key_j(key_s, order, elem, rank_lo, tgt)
+    key_s, order = _sort_j(key)
+    elem, key = _accept_target_key_j(key_s, order, elem, tgt)
+    key_s, order = _sort_j(key)
+    elem, key = _accept_id_key_j(key_s, order, elem)
+    return _sort_j(key)[1], elem
+
+
+def _cluster_accept(order, elem, tgt, node_w, cluster, cw, max_cluster_w):
+    """A clustering round's acceptance along its sorted order, and the
+    accepted moves: new cluster labels and weights."""
+    _count_trace("cluster_accept")
+    n_pad = node_w.shape[0]
+    with jax.named_scope("lp.accept"):
+        accept = _accept_prefix(order, elem, tgt, node_w,
+                                jnp.maximum(max_cluster_w - cw, 0.0))
     wmv = jnp.where(accept, node_w, 0.0)
-    out = jnp.where(accept, best_lab, n_pad)
+    out = jnp.where(accept, tgt, n_pad)
     src_c = jnp.where(accept, cluster, n_pad)
     cw = (cw
           - jax.ops.segment_sum(wmv, src_c, num_segments=n_pad + 1)[:n_pad]
           + jax.ops.segment_sum(wmv, out, num_segments=n_pad + 1)[:n_pad])
-    return jnp.where(accept, best_lab, cluster), cw
+    return jnp.where(accept, tgt, cluster), cw
 
 
 def _contract_nodes(esrc, edst, cluster, node_w, pinned, n):
@@ -624,8 +748,7 @@ def _contract_edges(ew, key_s, order, n_pad: int):
     _count_trace("contract_edges")
     e_pad = key_s.shape[0]
     w_s = ew[order]
-    seg_start = jnp.concatenate([jnp.ones(1, bool), key_s[1:] != key_s[:-1]])
-    gid = _prefix_count(seg_start.astype(_I32)) - 1
+    gid = _dense_rank(key_s)
     sums = jax.ops.segment_sum(w_s, gid, num_segments=e_pad,
                                indices_are_sorted=True)
     gkey = jax.ops.segment_max(key_s, gid, num_segments=e_pad,
@@ -725,8 +848,8 @@ def _lp_refine(esrc, edst, ew, nbr, wts, node_w, pinned, n, labels, loads,
         movers = free & (gains > 1e-12)
         room = jnp.maximum(cap - loads, 0.0)
         with jax.named_scope("lp.accept"):
-            acc = _accept_with_capacity(movers, best_lab, gains, node_w,
-                                        room, n_pad, slots=True)
+            acc = _accept_slots(movers, best_lab, gains, node_w, room,
+                                n_pad)
         wmv = jnp.where(acc, node_w, 0.0)
         old = jnp.where(acc, labels, k)
         new = jnp.where(acc, best_lab, k)
@@ -750,6 +873,11 @@ def _project(labels, node_map, pinned):
 _sort_j = _jit(_sort)
 _cluster_key_j = _jit(_cluster_key)
 _lp_cluster_round_j = _jit(_lp_cluster_round, static=("mode",))
+_accept_hi_key_j = _jit(_accept_hi_key)
+_accept_gain_key_j = _jit(_accept_gain_key)
+_accept_target_key_j = _jit(_accept_target_key)
+_accept_id_key_j = _jit(_accept_id_key)
+_cluster_accept_j = _jit(_cluster_accept)
 _contract_nodes_j = _jit(_contract_nodes)
 _contract_edges_j = _jit(_contract_edges, static=("n_pad",))
 _fennel_order_j = _jit(_fennel_order)
@@ -888,11 +1016,15 @@ def multilevel_partition_jax(
                 if r and c_mode == "sort":
                     key_s, order = _sort_j(
                         _cluster_key_j(cur[0], cur[1], cluster))
-                cluster, cw = _lp_cluster_round_j(
+                cw, tgt, key_lo, key_hi = _lp_cluster_round_j(
                     cur[0], cur[1], cur[2], lvl_nbr, lvl_wts, cur[3], cur[4],
                     cur_n, max_cluster_w, cluster if r else None, cw,
                     key_s, order, mode=c_mode)
+                cluster, cw = _cluster_accept_j(
+                    *_acceptance_order(tgt, key_lo, key_hi), tgt, cur[3],
+                    cluster, cw, max_cluster_w)
                 _count_agg(f"cluster.{c_mode}")
+                _count_agg("accept.sorted")
             if c_key is not None:
                 with span("vcycle.sync"):
                     jax.block_until_ready(cluster)

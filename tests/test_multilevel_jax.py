@@ -157,37 +157,123 @@ def _accept_case(case: str, k: int, n_pad: int):
     return movers, targets, gains, node_w, capacity
 
 
+def _sorted_accept(movers, targets, gains, node_w, capacity):
+    """Clustering's sorted acceptance, its pieces composed as the level
+    loop dispatches them (under x64)."""
+    import jax.numpy as jnp
+
+    tgt, key_lo, key_hi = mlj._accept_keys(
+        jnp.asarray(movers), jnp.asarray(targets.astype(np.int32)),
+        jnp.asarray(gains))
+    return np.asarray(mlj._accept_prefix(
+        *mlj._acceptance_order(tgt, key_lo, key_hi), tgt,
+        jnp.asarray(node_w), jnp.asarray(capacity)))
+
+
+def _oracle_accept(movers, targets, gains, node_w, capacity):
+    ids = np.nonzero(movers)[0]
+    want = np.zeros(movers.shape[0], dtype=bool)
+    want[ids] = _accept_with_capacity(ids, targets[ids], gains[ids], node_w,
+                                      capacity)
+    return want
+
+
 @pytest.mark.parametrize("case", ["binding", "slack", "ties", "wide", "idle"])
 @pytest.mark.parametrize("n_pad", [64, 1024])
 @pytest.mark.parametrize("k", [2, 8, 32])
-def test_accept_dense_slots_match_scatter_and_oracle(k, n_pad, case):
-    """Refine's acceptance over its k + 1 target slots accepts the same
-    movers as clustering's n_pad-segment scatter path and as the numpy
-    lexsort + grouped cumsum, on binding and slack capacities, equal gains
-    (id tie-break), 48-bit gains, non-movers and zero-capacity blocks."""
+def test_accept_slots_and_sorted_match_oracle(k, n_pad, case):
+    """Refine's acceptance over its k + 1 target slots and clustering's
+    sorted acceptance accept the same movers as the numpy lexsort +
+    grouped cumsum, on binding and slack capacities, equal gains (id
+    tie-break), 48-bit gains, non-movers and zero-capacity blocks."""
     import jax
     import jax.numpy as jnp
 
     movers, targets, gains, node_w, capacity = _accept_case(case, k, n_pad)
-    ids = np.nonzero(movers)[0]
-    want = np.zeros(n_pad, dtype=bool)
-    want[ids] = _accept_with_capacity(ids, targets[ids], gains[ids], node_w,
-                                      capacity)
+    want = _oracle_accept(movers, targets, gains, node_w, capacity)
     padded = np.zeros(n_pad)
     padded[:k] = capacity
     with jax.enable_x64(True):
         args = [jnp.asarray(a) for a in (movers, targets.astype(np.int32),
                                          gains, node_w)]
-        slots = np.asarray(mlj._accept_with_capacity(
-            *args, jnp.asarray(capacity), n_pad, slots=True))
-        scatter = np.asarray(mlj._accept_with_capacity(
-            *args, jnp.asarray(padded), n_pad))
+        slots = np.asarray(mlj._accept_slots(
+            *args, jnp.asarray(capacity), n_pad))
+        ordered = _sorted_accept(movers, targets, gains, node_w, padded)
     assert np.array_equal(slots, want)
-    assert np.array_equal(scatter, want)
+    assert np.array_equal(ordered, want)
     if case == "slack":
         assert np.array_equal(want, movers)
     elif case != "idle":
         assert 0 < want.sum() < movers.sum()  # the capacities bind
+
+
+def _node_accept_case(case: str, n_pad: int):
+    """Movers, node-id targets, gains, integer node weights and per-node
+    capacities for one clustering acceptance case."""
+    rng = np.random.default_rng([n_pad, len(case)])
+    movers = rng.random(n_pad) < 0.7
+    targets = rng.integers(0, n_pad, n_pad)
+    node_w = rng.integers(1, 6, n_pad).astype(np.float64)
+    gains = rng.integers(1, 64, n_pad).astype(np.float64)
+    if case == "hub":
+        # one target takes most movers (hundreds from n_pad 1,024 up)
+        hub = int(rng.integers(0, n_pad))
+        targets[rng.random(n_pad) < 0.6] = hub
+    elif case == "distinct":
+        targets = rng.permutation(n_pad)
+    elif case == "ties":
+        targets = rng.integers(0, max(n_pad // 16, 2), n_pad)
+        gains[:] = 5.0  # the id order alone decides
+    elif case == "wide":
+        # 48 significant bits in eight buckets of one f32 value each, so
+        # the bits below f32 precision alone order a bucket's gains
+        top = (1 << 47) + (rng.integers(0, 8, n_pad) << 24)
+        gains = (top + rng.integers(0, 1 << 24, n_pad)) * 2.0 ** -40
+        gains[1::3] = gains[::3][: gains[1::3].size]  # some exact ties
+        targets = rng.integers(0, max(n_pad // 8, 2), n_pad)
+    elif case == "edge":
+        # every row moves and every gain differs, so the smallest gain's
+        # rank is n_pad - 1; its row and a quarter of the others target
+        # n_pad - 1, where (target, gain rank) shares the sentinel's bits
+        movers[:] = True
+        gains = rng.permutation(n_pad).astype(np.float64) + 1.0
+        targets[rng.random(n_pad) < 0.25] = n_pad - 1
+        targets[np.argmin(gains)] = n_pad - 1
+    per_node = np.bincount(targets[movers], weights=node_w[movers],
+                           minlength=n_pad)
+    capacity = np.floor(per_node * rng.uniform(0.2, 0.8, n_pad))
+    if case == "distinct":
+        capacity = np.where(rng.random(n_pad) < 0.5, per_node, 0.0)
+    elif case == "zero":
+        capacity[rng.random(n_pad) < 0.4] = 0.0
+    gains[~movers] = rng.normal(size=int((~movers).sum()))  # never read
+    return movers, targets, gains, node_w, capacity
+
+
+@pytest.mark.parametrize("case", ["hub", "distinct", "ties", "wide", "zero",
+                                  "edge"])
+@pytest.mark.parametrize("n_pad", [64, 1024, 65536])
+def test_accept_sorted_node_targets_match_oracle(n_pad, case):
+    """Clustering's sorted acceptance over node-id targets accepts the
+    numpy oracle's movers: a hub target with hundreds of movers under a
+    binding capacity, all-distinct targets, equal gains (id order decides),
+    48-bit gains with exact ties, zero-capacity targets, and a mover of
+    target n_pad - 1 at the last gain rank, where its pair key shares the
+    sentinel's bits at n_pad 2^16."""
+    import jax
+
+    movers, targets, gains, node_w, capacity = _node_accept_case(case, n_pad)
+    want = _oracle_accept(movers, targets, gains, node_w, capacity)
+    with jax.enable_x64(True):
+        got = _sorted_accept(movers, targets, gains, node_w, capacity)
+    assert np.array_equal(got, want)
+    assert 0 < want.sum() < movers.sum()  # the capacities bind
+    if case == "hub":
+        hub = np.bincount(targets[movers]).argmax()
+        assert want[movers & (targets == hub)].sum() > 1
+    elif case == "edge":
+        edge = movers & (targets == n_pad - 1)
+        assert 0 < want[edge].sum() < edge.sum()
 
 
 # ------------------------------------------------- stream-level contract
@@ -249,8 +335,8 @@ def test_jit_cache_bounded_over_stream():
 def test_stream_accept_counts():
     """A stream whose batches coarsen: `agg_counts` counts a refine call a
     level and `lp_iters` clustering rounds a coarsened level, under the
-    `refine.` and `cluster.` keys alone, and the blocks are the sparse
-    engine's."""
+    `refine.` and `cluster.` keys, and each round's sorted acceptance under
+    `accept.sorted`; the blocks are the sparse engine's."""
     def cfg(engine):
         return BuffCutConfig(
             k=4, buffer_size=512, batch_size=256, d_max=512.0,
@@ -264,7 +350,10 @@ def test_stream_accept_counts():
     agg = mlj.agg_counts()
     refine = sum(v for key, v in agg.items() if key.startswith("refine."))
     cluster = sum(v for key, v in agg.items() if key.startswith("cluster."))
-    assert refine + cluster == sum(agg.values()), agg
+    accept = sum(v for key, v in agg.items() if key.startswith("accept."))
+    assert refine + cluster + accept == sum(agg.values()), agg
+    # every clustering round takes the sorted acceptance
+    assert agg["accept.sorted"] == cluster
     # one refine call per level and a clustering round per lp_iters each
     assert refine > stats.n_batches
     assert cluster >= 2 * (refine - stats.n_batches)
@@ -301,7 +390,7 @@ def test_agg_counts_level0_refine(mode, monkeypatch):
     assert agg["refine.dense"] > 0  # the coarse levels' refine calls
     assert agg[f"cluster.{mode}"] > 0  # level 0's clustering rounds
     assert set(agg) <= {f"{phase}.{m}" for phase in ("refine", "cluster")
-                        for m in ("ell", "dense", "sort")}
+                        for m in ("ell", "dense", "sort")} | {"accept.sorted"}
 
 
 def test_reset_agg_counts():

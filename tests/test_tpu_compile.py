@@ -122,3 +122,56 @@ def test_level0_refine_compiles(one_chip, no_cache, monkeypatch, mode, width, e_
             rounds=3, mode=mode,
         ).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == (mode == "ell")
+
+
+def _cluster_stage(name, s):
+    """(function, argument specs, static kwargs) of one stage of a level-0
+    clustering round at ROWS nodes and E_PAD edge slots."""
+    import repro.core.multilevel_jax as mlj
+
+    i32, u32, f64 = jnp.int32, jnp.uint32, jnp.float64
+    rows = [s((ROWS,), dt) for dt in (i32, u32, f64)]
+    r_i32, r_u32, r_f64 = rows
+    edges = (s((E_PAD,), i32), s((E_PAD,), i32), s((E_PAD,), f64),
+             s((1, 8), i32), s((1, 8), f64), r_f64, r_i32, s((), i32),
+             s((), f64))
+    if name == "round0":
+        return mlj._lp_cluster_round, (*edges, None, None, None, None), {
+            "mode": "sort"}
+    if name == "round-sort":
+        return mlj._lp_cluster_round, (
+            *edges, r_i32, r_f64, s((E_PAD,), u32), s((E_PAD,), i32)), {
+            "mode": "sort"}
+    return {
+        "sort": (mlj._sort, (r_u32,)),
+        "hi_key": (mlj._accept_hi_key, (r_u32, r_i32, r_u32)),
+        "gain_key": (mlj._accept_gain_key, (r_u32, r_i32, r_i32, r_i32, r_i32)),
+        "target_key": (mlj._accept_target_key, (r_u32, r_i32, r_i32, r_i32)),
+        "id_key": (mlj._accept_id_key, (r_u32, r_i32, r_i32)),
+        "cluster_accept": (mlj._cluster_accept, (
+            r_i32, r_i32, r_i32, r_f64, r_i32, r_f64, s((), f64))),
+    }[name] + ({},)
+
+
+@pytest.mark.parametrize("stage", [
+    "round0", "round-sort", "sort", "hi_key", "gain_key", "target_key",
+    "id_key", "cluster_accept",
+])
+def test_level0_cluster_stages_compile(one_chip, no_cache, stage):
+    """The clustering round of a δ = 32,768 batch at level 0, stage by
+    stage, under x64: the aggregation program (round 0's all-distinct path
+    and a later round's presorted sort mode), the shared single-key sort at
+    ROWS u32 keys, the four programs between the acceptance's sorts, and
+    the acceptance-and-move program.  The acceptance's programs hold no
+    loop: the bit-serial search is refine's alone."""
+    def s(shape, dt):
+        return _spec(one_chip, shape, dt)
+
+    fn, args, static = _cluster_stage(stage, s)
+    with jax.enable_x64(True):
+        text = jax.jit(fn, static_argnames=tuple(static)).lower(
+            *args, **static).compile().as_text()
+    if stage == "sort":
+        assert f"u32[{ROWS}]" in text and "u64" not in text
+    elif stage not in ("round0", "round-sort"):
+        assert " while(" not in text
