@@ -168,30 +168,18 @@ def bench_multilevel(smoke: bool) -> dict:
     rows = (
         ("sparse", MultilevelConfig(engine="sparse")),
         ("jax", MultilevelConfig(engine="jax")),
-        # measured-time aggregation-mode selection (ISSUE 7): steady-state
-        # row, so let the tuner explore + commit before timing
-        ("jax_autotune", MultilevelConfig(engine="jax", agg_autotune=True)),
     )
     for engine, cfg in rows:
-        if engine == "jax_autotune":
-            from repro.core.multilevel_jax import reset_agg_tuner
-
-            reset_agg_tuner()
-            for _ in range(8):
-                multilevel_partition(g, pinned, p, loads, cfg)
         labels[engine] = multilevel_partition(g, pinned, p, loads, cfg)
         t = _best_of(
             lambda cfg=cfg: multilevel_partition(g, pinned, p, loads, cfg),
             reps)
         out["engines"][engine] = {"ms": t * 1e3}
-    for engine in ("jax", "jax_autotune"):
-        assert np.array_equal(labels["sparse"], labels[engine]), \
-            "engine parity broke — bench refuses to time unequal work"
+    assert np.array_equal(labels["sparse"], labels["jax"]), \
+        "engine parity broke — bench refuses to time unequal work"
     out["cut_ratio"] = cut_ratio(g, labels["sparse"])
     out["jax_over_sparse"] = (out["engines"]["jax"]["ms"]
                               / out["engines"]["sparse"]["ms"])
-    out["jax_autotune_over_sparse"] = (out["engines"]["jax_autotune"]["ms"]
-                                       / out["engines"]["sparse"]["ms"])
     return out
 
 
@@ -265,9 +253,7 @@ def main() -> None:
     print(f"multilevel e2e (n={ml['n']}, k={ml['k']}): "
           f"sparse {ml['engines']['sparse']['ms']:8.1f} ms  "
           f"jax {ml['engines']['jax']['ms']:8.1f} ms  "
-          f"jax+autotune {ml['engines']['jax_autotune']['ms']:8.1f} ms  "
-          f"({ml['jax_over_sparse']:.2f}x / "
-          f"{ml['jax_autotune_over_sparse']:.2f}x, identical labels)")
+          f"({ml['jax_over_sparse']:.2f}x, identical labels)")
     for engine, row in report["e2e"]["engines"].items():
         print(f"e2e {engine:>11}: {row['runtime_s']:.2f} s  cut_ratio {row['cut_ratio']:.4f}")
     oc = report["outofcore"]

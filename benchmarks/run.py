@@ -1,7 +1,7 @@
 """Benchmark driver — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows. The roofline/dry-run analyses
-are separate (heavier) modules: benchmarks.roofline and repro.launch.dryrun.
+Prints ``name,us_per_call,derived`` CSV rows; a suite name (or part of
+one) as the first argument runs only the matching suites.
 """
 from __future__ import annotations
 

@@ -1,72 +1,13 @@
-"""Render bench outputs into EXPERIMENTS.md tables.
+"""Render BENCH_hotpath.json into an EXPERIMENTS.md table: every section,
+including the `checkpoint` and `restream_outofcore` sections merged in by
+bench_checkpoint.py and bench_restream.py.
 
-Modes: `dryrun` / `roofline` (jsonl trajectories) and `hotpath`
-(BENCH_hotpath.json — every section, including the `checkpoint` and
-`restream_outofcore` sections merged in by bench_checkpoint.py and
-bench_restream.py).
+    python benchmarks/summarize.py hotpath [BENCH_hotpath.json]
 """
 from __future__ import annotations
 
 import json
 import sys
-
-
-def load(path: str) -> list[dict]:
-    rows = []
-    with open(path) as f:
-        for line in f:
-            if line.strip():
-                rows.append(json.loads(line))
-    return rows
-
-
-def dryrun_table(path: str = "dryrun_results.jsonl") -> str:
-    rows = load(path)
-    # keep the latest entry per (arch, shape, mesh)
-    latest = {}
-    for r in rows:
-        latest[(r["arch"], r["shape"], r.get("mesh", ""))] = r
-    out = ["| arch | shape | mesh | status | peak GB/dev | coll MB/dev | compile s |",
-           "|---|---|---|---|---|---|---|"]
-    n_ok = n_skip = n_fail = 0
-    for (a, s, m), r in sorted(latest.items()):
-        if r["status"] == "ok":
-            n_ok += 1
-            gb = r["bytes_per_device"]["peak"] / 1e9
-            coll = r["collectives"]["total"] / 1e6
-            flag = " ⚠" if gb > 16 else ""
-            out.append(
-                f"| {a} | {s} | {m} | ok | {gb:.2f}{flag} | {coll:.0f} | {r['compile_s']} |"
-            )
-        elif r["status"] == "skip":
-            n_skip += 1
-            out.append(f"| {a} | {s} | {m} | skip | — | — | — |")
-        else:
-            n_fail += 1
-            out.append(f"| {a} | {s} | {m} | FAIL | — | — | — |")
-    out.append("")
-    out.append(f"Totals: {n_ok} ok, {n_skip} skip, {n_fail} fail. "
-               "⚠ = exceeds the 16 GB/chip HBM budget at baseline (hillclimb target).")
-    return "\n".join(out)
-
-
-def roofline_table(path: str = "roofline_results.jsonl") -> str:
-    rows = [r for r in load(path) if r.get("status") == "ok"]
-    latest = {}
-    for r in rows:
-        latest[(r["arch"], r["shape"], r.get("mesh", ""))] = r
-    out = ["| arch | shape | t_comp ms | t_mem ms | t_coll ms | bottleneck | useful% | roofline frac |",
-           "|---|---|---|---|---|---|---|---|"]
-    for (a, s, _m), r in sorted(latest.items()):
-        rf = r["roofline"]
-        dom = max(rf["t_compute_s"], rf["t_memory_s"], rf["t_collective_s"])
-        frac = rf["t_compute_s"] / dom if dom else 0.0
-        out.append(
-            f"| {a} | {s} | {rf['t_compute_s']*1e3:.2f} | {rf['t_memory_s']*1e3:.2f} "
-            f"| {rf['t_collective_s']*1e3:.2f} | {r['dominant']} "
-            f"| {rf['useful_flops_frac']*100:.1f} | {frac:.2f} |"
-        )
-    return "\n".join(out)
 
 
 def hotpath_table(path: str = "BENCH_hotpath.json") -> str:
@@ -87,9 +28,7 @@ def hotpath_table(path: str = "BENCH_hotpath.json") -> str:
                    f"| < 3.0 | scan growth {e['scan_growth']:.2f} |")
     ml = r.get("multilevel")
     if ml:
-        tuned = ml.get("jax_autotune_over_sparse")
-        tuned_s = f", autotuned {tuned:.2f}x" if tuned is not None else ""
-        out.append(f"| multilevel | jax {ml['jax_over_sparse']:.2f}x sparse{tuned_s} "
+        out.append(f"| multilevel | jax {ml['jax_over_sparse']:.2f}x sparse "
                    f"| <= 6.0x | identical labels |")
     e2e = r.get("e2e")
     if e2e:
@@ -144,11 +83,6 @@ def hotpath_table(path: str = "BENCH_hotpath.json") -> str:
 
 
 if __name__ == "__main__":
-    kind = sys.argv[1] if len(sys.argv) > 1 else "dryrun"
-    path = sys.argv[2] if len(sys.argv) > 2 else None
-    if kind == "dryrun":
-        print(dryrun_table(path or "dryrun_results.jsonl"))
-    elif kind == "hotpath":
-        print(hotpath_table(path or "BENCH_hotpath.json"))
-    else:
-        print(roofline_table(path or "roofline_results.jsonl"))
+    if len(sys.argv) > 1 and sys.argv[1] != "hotpath":
+        sys.exit(f"summarize.py: unknown mode {sys.argv[1]!r} (only 'hotpath')")
+    print(hotpath_table(sys.argv[2] if len(sys.argv) > 2 else "BENCH_hotpath.json"))

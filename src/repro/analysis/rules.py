@@ -129,25 +129,25 @@ class Rule:
 
 
 class NoEagerHeavyImports(Rule):
-    """RPR001 — no eager accelerator imports outside kernels/models/train/configs.
+    """RPR001 — no eager accelerator imports outside kernels/.
 
     Contract: `jax` costs seconds of import time and is fork-hostile; the
-    partitioning path (core/, graphs/, distributed/shard_driver, serve/,
-    api/, launch entry points) must import it lazily — inside the function
-    or engine branch that needs it — so CPU/out-of-core runs and forked
-    shard workers never pay or inherit the accelerator stack.
+    partitioning path (core/, graphs/, distributed/, serve/, api/,
+    configs/) must import it lazily — inside the function or engine branch
+    that needs it — so CPU/out-of-core runs and forked shard workers never
+    pay or inherit the accelerator stack.
 
     Incident: PR 8 made `distributed/` PEP-562-lazy because forked shard
-    workers crashed under an inherited XLA runtime; PR 9 made
-    launch/serve.py's LM/DLRM imports lazy so `--arch partition` serving
-    never pays them.  Both were hand fixes to a convention nothing
-    enforced.  Whole-module jax engines (e.g. core/multilevel_jax.py)
-    carry a per-line noqa — they are the lazy target, not the caller.
+    workers crashed under an inherited XLA runtime; PR 9 made the serving
+    entry point's jax imports lazy so partition serving never pays them.
+    Both were hand fixes to a convention nothing enforced.  Whole-module
+    jax engines (e.g. core/multilevel_jax.py) carry a per-line noqa — they
+    are the lazy target, not the caller.
     """
 
     id = "RPR001"
     title = "no-eager-heavy-imports"
-    exempt = ("kernels/*", "models/*", "train/*", "configs/*")
+    exempt = ("kernels/*",)
     HEAVY = ("jax",)
 
     def _is_heavy(self, name: "str | None") -> bool:
@@ -163,9 +163,8 @@ class NoEagerHeavyImports(Rule):
                         yield (
                             stmt.lineno, stmt.col_offset,
                             f"eager top-level import of {a.name!r}: modules outside "
-                            "kernels/, models/, train/, configs/ must import jax "
-                            "lazily (inside the function or engine branch that "
-                            "needs it)",
+                            "kernels/ must import jax lazily (inside the "
+                            "function or engine branch that needs it)",
                         )
                         break
             elif isinstance(stmt, ast.ImportFrom):
@@ -173,8 +172,7 @@ class NoEagerHeavyImports(Rule):
                     yield (
                         stmt.lineno, stmt.col_offset,
                         f"eager top-level import from {stmt.module!r}: modules "
-                        "outside kernels/, models/, train/, configs/ must import "
-                        "jax lazily",
+                        "outside kernels/ must import jax lazily",
                     )
 
 
@@ -539,8 +537,9 @@ class DurableWrite(Rule):
 
     Incident: PR 6 built this pattern into core/checkpoint.py
     (`save_checkpoint`) after designing for SIGKILL-mid-run crash tests;
-    train/checkpoint.py predated it and renamed un-fsynced npz files into
-    place — exactly the torn-checkpoint class the pattern exists to kill.
+    an older model-checkpoint writer predated it and renamed un-fsynced
+    npz files into place — exactly the torn-checkpoint class the pattern
+    exists to kill.
 
     Approximation: "written under a durable path" is detected textually —
     a write-mode `open()` whose path expression does not mention ``tmp``.
@@ -552,7 +551,6 @@ class DurableWrite(Rule):
     title = "durable-write"
     modules = (
         "core/checkpoint.py",
-        "train/checkpoint.py",
         "graphs/stream_io.py",
         "graphs/io.py",
     )

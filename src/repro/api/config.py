@@ -40,7 +40,7 @@ _BUFFCUT_KEYS = (
 )
 _ML_KEYS = (
     "coarsen_target", "max_levels", "lp_iters", "refine_rounds",
-    "min_shrink", "seed", "agg_autotune",
+    "min_shrink", "seed",
 )  # plus "engine", routed to ml below
 _VEC_KEYS = ("wave", "chunk")  # plus "vec_engine" -> VectorizedConfig.engine
 _PIPE_KEYS = ("queue_depth", "read_ahead", "prefetch_batches")

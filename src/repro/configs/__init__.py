@@ -1,45 +1,1 @@
-"""Architecture registry: `--arch <id>` resolution for all 10 assigned archs."""
-from repro.configs.base import ArchSpec, ShapeDef
-from repro.configs import (
-    llama4_scout_17b_a16e,
-    moonshot_v1_16b_a3b,
-    stablelm_3b,
-    command_r_plus_104b,
-    h2o_danube_1_8b,
-    egnn,
-    meshgraphnet,
-    schnet,
-    graphsage_reddit,
-    dlrm_mlperf,
-)
-from repro.configs import buffcut_paper
-
-ARCHS: dict[str, ArchSpec] = {
-    spec.arch_id: spec
-    for spec in [
-        llama4_scout_17b_a16e.SPEC,
-        moonshot_v1_16b_a3b.SPEC,
-        stablelm_3b.SPEC,
-        command_r_plus_104b.SPEC,
-        h2o_danube_1_8b.SPEC,
-        egnn.SPEC,
-        meshgraphnet.SPEC,
-        schnet.SPEC,
-        graphsage_reddit.SPEC,
-        dlrm_mlperf.SPEC,
-    ]
-}
-
-
-def get_arch(arch_id: str) -> ArchSpec:
-    if arch_id not in ARCHS:
-        raise KeyError(f"unknown arch {arch_id!r}; known: {sorted(ARCHS)}")
-    return ARCHS[arch_id]
-
-
-def all_cells() -> list[tuple[str, str]]:
-    """Every (arch, shape) pair — 40 total."""
-    return [c for spec in ARCHS.values() for c in spec.cells()]
-
-
-__all__ = ["ARCHS", "get_arch", "all_cells", "ArchSpec", "ShapeDef", "buffcut_paper"]
+"""Partitioner presets: the paper's parameters (`buffcut_paper`)."""

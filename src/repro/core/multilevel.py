@@ -17,7 +17,7 @@ import dataclasses
 import numpy as np
 
 from repro.graphs.csr import CSRGraph
-from repro.core.fennel import FennelParams, fennel_penalty
+from repro.core.fennel import FennelParams, fennel_gain_sequential
 from repro.core.histogram import (
     aggregate_by_key,
     best_label_per_src,
@@ -42,11 +42,6 @@ class MultilevelConfig:
     min_shrink: float = 0.95       # stop coarsening if shrink factor above
     seed: int = 0
     engine: str = "auto"           # "auto" | "sparse" | "ell" | "jax"
-    # jax engine only: replace the static aggregation-mode shape rules with
-    # measured-time selection per (phase, level shape) — see
-    # multilevel_jax._AggTuner.  Labels are unaffected (cross-mode parity);
-    # off by default so compilation counts stay deterministic for tests.
-    agg_autotune: bool = False
 
     def __post_init__(self) -> None:
         if self.engine not in _ENGINES:
@@ -250,17 +245,9 @@ def initial_fennel(
 ) -> np.ndarray:
     """Weighted Fennel on the coarsest graph, heaviest free nodes first.
 
-    Sequential by construction (each step must see earlier placements).
-    The per-step scoring runs through the shared gain engine in
-    kernels/fennel_gain.py — `fennel_gain_sequential`, the scalar host
-    loop, which at coarse-graph sizes (~10²-10³ nodes, small k) beats a
-    per-step numpy gather by ~5x and is pinned bit-identical to the
-    vectorized loop it replaced.
+    Sequential by construction (each step must see earlier placements):
+    the per-step scoring is core/fennel.py's `fennel_gain_sequential`.
     """
-    # deferred: fennel_gain.py is jax-resident (the Pallas kernel lives
-    # there); the sequential engine itself is a scalar host loop
-    from repro.kernels.fennel_gain import fennel_gain_sequential
-
     labels = pinned.copy()
     free = np.nonzero(pinned < 0)[0]
     order = free[np.lexsort((free, -g.node_w[free]))]

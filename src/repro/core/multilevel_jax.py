@@ -40,9 +40,6 @@ scalar pulls (free-node count, coarse size) that drive the level loop.
 """
 from __future__ import annotations
 
-
-import time
-
 import jax  # repro: noqa RPR001 -- whole-module jax engine; imported lazily by core/multilevel.py
 import jax.numpy as jnp  # repro: noqa RPR001 -- jax engine module
 import numpy as np
@@ -64,11 +61,6 @@ DENSE_VOLUME_CAP = (1 << 22) if jax.default_backend() == "tpu" else (1 << 18)
 
 # tests force a mode ("dense" | "ell" | "sort") to pin cross-mode parity
 MODE_OVERRIDE: str | None = None
-
-# dense-mode exploration ceiling for the autotuner: above this padded
-# volume a dense candidate would allocate a count matrix big enough to
-# matter, so the tuner trusts the static shape rule instead of probing
-_AUTOTUNE_DENSE_CAP = 1 << 24
 
 # buffer donation frees the device copies of loop-carried state; the CPU
 # backend does not implement donation and warns, so gate on backend
@@ -120,64 +112,6 @@ def reset_agg_counts() -> None:
 def _jit(fn, *, static=(), donate=()):
     return jax.jit(fn, static_argnames=static,
                    donate_argnums=donate if _DONATE else ())
-
-
-class _AggTuner:
-    """Measured-time aggregation-mode selection (`MultilevelConfig.agg_autotune`).
-
-    The static shape rules in `_pick_mode` encode backend priors (dense is
-    fast on TPU, sort takes over earlier on CPU), but priors lose to
-    measurement: on CPU the dense row-reduce over a padded label domain can
-    be 2-3x slower than the segmented sort at shapes the rules call dense.
-    The tuner is keyed by ``(phase, n_pad, l_pad)`` — exactly the static
-    shapes that select compiled kernels — and for each key round-robins the
-    candidate modes: one *untimed* warmup call per mode (absorbs jit
-    compilation), then ``TIMED`` timed calls per mode blocking on the result
-    (async dispatch would otherwise hide the work), then commits to the
-    fastest mean and never blocks again.  All modes produce identical
-    labels (cross-mode parity is pinned by tests/test_multilevel_jax.py),
-    so exploration changes wall clock, never output.
-    """
-
-    WARMUP = 1
-    TIMED = 2
-
-    def __init__(self) -> None:
-        self._samples: dict[tuple, dict[str, list[float]]] = {}
-        self._decided: dict[tuple, str] = {}
-
-    def choose(self, key: tuple, candidates: tuple[str, ...]) -> tuple[str, bool]:
-        """Return ``(mode, explore)``; ``explore`` asks the caller to time
-        this call and feed the duration back through `record`."""
-        if key in self._decided:
-            return self._decided[key], False
-        per = self._samples.setdefault(key, {m: [] for m in candidates})
-        mode = min(candidates, key=lambda m: len(per[m]))
-        if len(per[mode]) >= self.WARMUP + self.TIMED:
-            # every candidate fully sampled: mean over the post-warmup calls
-            best = min(
-                candidates,
-                key=lambda m: sum(per[m][self.WARMUP:]) / self.TIMED,
-            )
-            self._decided[key] = best
-            return best, False
-        return mode, True
-
-    def record(self, key: tuple, mode: str, dt: float) -> None:
-        self._samples[key][mode].append(dt)
-
-
-_TUNER = _AggTuner()
-
-
-def agg_decisions() -> dict[tuple, str]:
-    """Committed (phase, n_pad, l_pad) -> mode picks so far (bench/tests)."""
-    return dict(_TUNER._decided)
-
-
-def reset_agg_tuner() -> None:
-    global _TUNER
-    _TUNER = _AggTuner()
 
 
 # --------------------------------------------------------------------------
@@ -963,29 +897,15 @@ def multilevel_partition_jax(
             free_deg = int(np.max(np.diff(g.indptr)[free_total], initial=1))
             w_pad = bucket_size(free_deg, minimum=8)
 
-            autotune = bool(getattr(cfg, "agg_autotune", False))
+            def cluster_mode(level: int, np_l: int) -> str:
+                return _pick_mode(np_l, np_l, w_pad if level == 0 else None)
 
-            def tuned(phase: str, np_l: int, l_pad: int, base: str):
-                """(mode, timing key | None) — key is non-None while the tuner
-                still wants a blocking measurement for this call."""
-                if (not autotune or MODE_OVERRIDE is not None or base == "ell"
-                        or np_l * l_pad > _AUTOTUNE_DENSE_CAP):
-                    return base, None
-                key = (phase, np_l, l_pad)
-                mode, explore = _TUNER.choose(key, ("dense", "sort"))
-                return mode, (key if explore else None)
-
-            def cluster_mode(level: int, np_l: int):
-                base = _pick_mode(np_l, np_l, w_pad if level == 0 else None)
-                return tuned("cluster", np_l, np_l, base)
-
-            def refine_mode(level: int, np_l: int):
-                base = _pick_mode(np_l, p.k, w_pad if level == 0 else None)
-                return tuned("refine", np_l, p.k, base)
+            def refine_mode(level: int, np_l: int) -> str:
+                return _pick_mode(np_l, p.k, w_pad if level == 0 else None)
 
             dummy_nbr = jnp.zeros((1, 8), dtype=jnp.int32)
             dummy_wts = jnp.zeros((1, 8), dtype=jnp.float64)
-            if "ell" in (cluster_mode(0, n_pad)[0], refine_mode(0, n_pad)[0]):
+            if "ell" in (cluster_mode(0, n_pad), refine_mode(0, n_pad)):
                 with span("vcycle.pack_ell"):
                     nbr_h, wts_h, _ = g.to_ell_padded(
                         np.arange(n, dtype=np.int64),
@@ -1007,9 +927,7 @@ def multilevel_partition_jax(
                 break
             lvl_nbr = nbr if level == 0 else dummy_nbr
             lvl_wts = wts if level == 0 else dummy_wts
-            c_mode, c_key = cluster_mode(level, cur_np)
-            if c_key is not None:
-                t0 = time.perf_counter()
+            c_mode = cluster_mode(level, cur_np)
             cluster = jnp.arange(cur_np, dtype=jnp.int32)
             cw = key_s = order = None
             for r in range(cfg.lp_iters):
@@ -1025,10 +943,6 @@ def multilevel_partition_jax(
                     cluster, cw, max_cluster_w)
                 _count_agg(f"cluster.{c_mode}")
                 _count_agg("accept.sorted")
-            if c_key is not None:
-                with span("vcycle.sync"):
-                    jax.block_until_ready(cluster)
-                _TUNER.record(c_key, c_mode, time.perf_counter() - t0)
             cw2, cpin2, node_map, nc_dev, ekey = _contract_nodes_j(
                 cur[0], cur[1], cluster, cur[3], cur[4], cur_n)
             es2, ed2, ew2, ne_dev = _contract_edges_j(
@@ -1080,9 +994,7 @@ def multilevel_partition_jax(
             _fennel_order_j(cur[3], cur[4], cur_n),
             jnp.asarray(np.asarray(loads_base, dtype=np.float64)),
             alpha, gamma, cap, w_c=w_c)
-        r_mode, r_key = refine_mode(level, cur_np)
-        if r_key is not None:
-            t0 = time.perf_counter()
+        r_mode = refine_mode(level, cur_np)
         labels, loads = _lp_refine_j(
             cur[0], cur[1], cur[2],
             nbr if level == 0 else dummy_nbr,
@@ -1090,17 +1002,11 @@ def multilevel_partition_jax(
             cur[3], cur[4], cur_n, labels, loads, cap,
             rounds=cfg.refine_rounds, mode=r_mode)
         _count_agg(f"refine.{r_mode}")
-        if r_key is not None:
-            with span("vcycle.sync"):
-                jax.block_until_ready((labels, loads))
-            _TUNER.record(r_key, r_mode, time.perf_counter() - t0)
 
         # ---- uncoarsen + refine
         for fine, fine_n, node_map, lvl in reversed(levels):
             labels = _project_j(labels, node_map, fine[4])
-            r_mode, r_key = refine_mode(lvl, fine[3].shape[0])
-            if r_key is not None:
-                t0 = time.perf_counter()
+            r_mode = refine_mode(lvl, fine[3].shape[0])
             labels, loads = _lp_refine_j(
                 fine[0], fine[1], fine[2],
                 nbr if lvl == 0 else dummy_nbr,
@@ -1108,10 +1014,6 @@ def multilevel_partition_jax(
                 fine[3], fine[4], fine_n, labels, loads, cap,
                 rounds=cfg.refine_rounds, mode=r_mode)
             _count_agg(f"refine.{r_mode}")
-            if r_key is not None:
-                with span("vcycle.sync"):
-                    jax.block_until_ready((labels, loads))
-                _TUNER.record(r_key, r_mode, time.perf_counter() - t0)
 
         # the single device->host transfer of the batch assignment
         with span("vcycle.sync"):

@@ -31,8 +31,8 @@ under the same name):
                          from entry to the first level's dispatch
                          (core/multilevel_jax.py)
   vcycle.sync            the jax engine's host waits on one device->host
-                         pull: a level's scalars, the coarsest degree, an
-                         autotune timing, the labels back
+                         pull: a level's scalars, the coarsest degree,
+                         the labels back
 
 These are the stages, `SPANS`.  A span nested inside a stage is not a
 stage of its own:

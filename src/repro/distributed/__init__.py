@@ -1,11 +1,8 @@
-"""Distributed runtime: shard-parallel partitioning, sharding rules,
-compression, overlap, GNN placement.
+"""Distributed runtime: shard-parallel partitioning and GNN placement.
 
 Submodules are imported lazily (PEP 562): `shard_driver` is pure
 numpy + threads and must stay importable — and fork-safe for its process
-backend — without dragging in the jax-backed model-parallel modules
-(`sharding`, `compression`, `overlap`), whose attributes still resolve
-through this package exactly as before.
+backend — without importing anything else of this package.
 """
 
 _LAZY = {
@@ -15,19 +12,6 @@ _LAZY = {
     "ShardWorkerError": "shard_driver",
     "shard_partition": "shard_driver",
     "SHARD_BACKENDS": "shard_driver",
-    # model-parallel runtime (jax)
-    "ShardingRules": "sharding",
-    "lm_sharding_rules": "sharding",
-    "gnn_sharding_rules": "sharding",
-    "dlrm_sharding_rules": "sharding",
-    "param_shardings": "sharding",
-    "batch_shardings": "sharding",
-    "topk_compress": "compression",
-    "topk_decompress": "compression",
-    "error_feedback_update": "compression",
-    "quantize_int8": "compression",
-    "dequantize_int8": "compression",
-    "collective_matmul_allgather": "overlap",
 }
 
 __all__ = list(_LAZY)

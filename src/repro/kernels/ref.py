@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracle for the Pallas kernel (the allclose target)."""
 from __future__ import annotations
 
 import jax
@@ -13,74 +13,3 @@ def ell_histogram_ref(nbr_blk: jnp.ndarray, nbr_w: jnp.ndarray, k: int) -> jnp.n
     """
     onehot = jax.nn.one_hot(nbr_blk, k, dtype=nbr_w.dtype)  # -1 rows are all-0
     return jnp.einsum("bw,bwk->bk", nbr_w, onehot)
-
-
-def fennel_gain_ref(
-    nbr_blk: jnp.ndarray,
-    nbr_w: jnp.ndarray,
-    loads: jnp.ndarray,
-    node_w: jnp.ndarray,
-    *,
-    alpha: float,
-    gamma: float,
-    cap: float,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused Fennel decision: (best block, best score) per node.
-
-    score_i = w(N(v) ∩ V_i) − α·γ·load_i^(γ−1);  infeasible (over cap) = −inf;
-    ties break toward the lower block id (deterministic).
-    If every block is infeasible, falls back to argmin(loads).
-    """
-    k = loads.shape[0]
-    counts = ell_histogram_ref(nbr_blk, nbr_w, k)
-    penalty = alpha * gamma * jnp.power(jnp.maximum(loads, 0.0), gamma - 1.0)
-    score = counts - penalty[None, :]
-    feasible = (loads[None, :] + node_w[:, None]) <= cap
-    masked = jnp.where(feasible, score, -jnp.inf)
-    best = jnp.argmax(masked, axis=1).astype(jnp.int32)
-    fallback = jnp.argmin(loads).astype(jnp.int32)
-    any_ok = feasible.any(axis=1)
-    best = jnp.where(any_ok, best, fallback)
-    best_score = jnp.take_along_axis(masked, best[:, None].astype(jnp.int32), axis=1)[:, 0]
-    return best, best_score
-
-
-def embedding_bag_ref(
-    table: jnp.ndarray, idx: jnp.ndarray, mask: jnp.ndarray
-) -> jnp.ndarray:
-    """pooled[b] = sum_l table[idx[b, l]] * mask[b, l].
-
-    table: (V, D); idx: (B, L) int32 already clamped to [0, V); mask: (B, L).
-    """
-    rows = table[idx]  # (B, L, D)
-    return (rows * mask[..., None]).sum(axis=1)
-
-
-def swa_attention_decode_ref(
-    q: jnp.ndarray,
-    k_win: jnp.ndarray,
-    v_win: jnp.ndarray,
-    pos: jnp.ndarray,
-    win_start: jnp.ndarray,
-    *,
-    window: int,
-) -> jnp.ndarray:
-    """Sliding-window decode attention (one new query token), GQA layout.
-
-    q:       (B, KVH, G, D) — query heads grouped under their KV head.
-    k_win:   (B, KVH, Wp, D) — cache window slice (Wp >= window, aligned).
-    v_win:   (B, KVH, Wp, D).
-    pos:     (B,) int32 — number of tokens already in the cache (new token
-             attends to positions [max(0, pos-window), pos)).
-    win_start: (B,) int32 — absolute position of k_win[:, :, 0].
-    """
-    B, KVH, Wp, D = k_win.shape
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, q.dtype))
-    scores = jnp.einsum("bhgd,bhwd->bhgw", q, k_win) * scale
-    abs_pos = win_start[:, None] + jnp.arange(Wp)[None, :]  # (B, Wp)
-    lo = jnp.maximum(pos - window, 0)[:, None]
-    valid = (abs_pos >= lo) & (abs_pos < pos[:, None])  # (B, Wp)
-    scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    probs = jnp.where(jnp.isnan(probs), 0.0, probs)  # fully-masked rows
-    return jnp.einsum("bhgw,bhwd->bhgd", probs, v_win)

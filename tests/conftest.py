@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here on purpose — tests must see the
-host's real device count (1); only launch/dryrun.py forces 512 devices."""
+host's real device count (1)."""
 import pytest
 
 

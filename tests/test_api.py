@@ -432,7 +432,7 @@ def test_buffcut_config_json_roundtrip_golden():
         "ml": {
             "coarsen_target": 160, "max_levels": 10, "lp_iters": 2,
             "refine_rounds": 3, "min_shrink": 0.95, "seed": 3,
-            "engine": "jax", "agg_autotune": False,
+            "engine": "jax",
         },
         "collect_stats": True,
     }

@@ -236,12 +236,11 @@ def _fennel_sequential_reference(g, order, labels, loads, *, alpha, gamma,
 
 @pytest.mark.parametrize("gamma", [1.25, 1.5, 3.0])
 def test_fennel_gain_sequential_matches_vectorized_reference(gamma):
-    """kernels/fennel_gain.py::fennel_gain_sequential is bit-identical to
+    """core/fennel.py::fennel_gain_sequential is bit-identical to
     the per-step numpy loop it replaced — the `_pow_scalar` fast paths
     (gamma-1 ∈ {0.25 generic, 0.5 sqrt, 2.0 square}) and the left-to-right
-    connectivity adds are the contract (referenced by the kernel
-    docstring)."""
-    from repro.kernels.fennel_gain import fennel_gain_sequential
+    connectivity adds are the contract (referenced by its docstring)."""
+    from repro.core.fennel import fennel_gain_sequential
 
     rng = np.random.default_rng(13)
     g = rmat_graph(256, 6, seed=21)

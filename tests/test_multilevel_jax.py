@@ -35,6 +35,18 @@ def _params(g, k, eps=0.1):
                         m_total=g.total_edge_weight(), eps=eps)
 
 
+# a power-law graph with hubs and a planar mesh with none, built lazily so
+# collection stays cheap
+_GRAPHS = {
+    "rmat": lambda: rmat_graph(512, 8, seed=3),
+    "grid": lambda: grid_mesh_graph(24),
+}
+_ORDERED_GRAPHS = {
+    "rmat": lambda: rmat_graph(384, 8, seed=11),
+    "grid": lambda: grid_mesh_graph(20),
+}
+
+
 # ------------------------------------------------------------- bucketing
 
 def test_bucket_size():
@@ -72,13 +84,16 @@ def test_to_ell_padded_buckets():
 
 # ---------------------------------------------------- mode/label parity
 
+@pytest.mark.parametrize("graph", sorted(_GRAPHS))
+@pytest.mark.parametrize("k", [8, 32, 64, 128, 130])
 @pytest.mark.parametrize("mode", ["dense", "sort", "ell"])
-def test_jax_modes_match_sparse_oracle(mode):
+def test_jax_modes_match_sparse_oracle(mode, k, graph):
     """All three aggregation modes produce the sparse oracle's labels on a
-    batch-model graph with pinned aux nodes and preexisting loads."""
+    batch-model graph with pinned aux nodes and preexisting loads, from the
+    paper's k = 32 up to k past the 128 label columns of one histogram
+    tile, on a power-law graph and on a mesh."""
     rng = np.random.default_rng(0)
-    g = rmat_graph(512, 8, seed=3)
-    k = 8
+    g = _GRAPHS[graph]()
     p = _params(g, k, eps=0.05)
     block = np.full(g.n, -1, dtype=np.int64)
     block[:200] = rng.integers(0, k, 200)
@@ -97,13 +112,14 @@ def test_jax_modes_match_sparse_oracle(mode):
     assert np.array_equal(ref, got)
 
 
+@pytest.mark.parametrize("graph", sorted(_ORDERED_GRAPHS))
+@pytest.mark.parametrize("k", [6, 64])
 @pytest.mark.parametrize("ordering", ["natural", "bfs", "adversarial"])
-def test_jax_exact_labels_across_orderings(ordering):
+def test_jax_exact_labels_across_orderings(ordering, k, graph):
     order = {"natural": source_order, "bfs": bfs_order,
              "adversarial": konect_order}[ordering]
-    base = rmat_graph(384, 8, seed=11)
+    base = _ORDERED_GRAPHS[graph]()
     g = apply_order(base, order(base))
-    k = 6
     p = _params(g, k)
     pinned = np.full(g.n, -1, dtype=np.int64)
     ref = multilevel_partition(g, pinned, p, np.zeros(k),
@@ -179,8 +195,10 @@ def _oracle_accept(movers, targets, gains, node_w, capacity):
 
 
 @pytest.mark.parametrize("case", ["binding", "slack", "ties", "wide", "idle"])
-@pytest.mark.parametrize("n_pad", [64, 1024])
-@pytest.mark.parametrize("k", [2, 8, 32])
+@pytest.mark.parametrize("k,n_pad", [
+    (k, n_pad) for k in (2, 8, 32, 64, 128) for n_pad in (64, 1024)
+    if k <= n_pad
+])
 def test_accept_slots_and_sorted_match_oracle(k, n_pad, case):
     """Refine's acceptance over its k + 1 target slots and clustering's
     sorted acceptance accept the same movers as the numpy lexsort +
@@ -285,14 +303,25 @@ def _stream_cfg(engine, k=4, batch=64):
     )
 
 
-def test_stream_driver_identical_blocks():
-    """The vectorized driver commits identical assignments batch after
-    batch when the multilevel engine moves to the device."""
+def _vectorized(g, cfg):
+    return buffcut_partition_vectorized(g, cfg, wave=8, chunk=8)
+
+
+def _pipelined(g, cfg):
+    from repro.core.pipeline import _buffcut_partition_pipelined
+
+    return _buffcut_partition_pipelined(g, cfg)
+
+
+@pytest.mark.parametrize("k", [4, 32, 64])
+@pytest.mark.parametrize("driver", ["vectorized", "pipelined"])
+def test_stream_driver_identical_blocks(driver, k):
+    """The vectorized and the pipelined driver commit identical assignments
+    batch after batch when the multilevel engine moves to the device."""
+    run = {"vectorized": _vectorized, "pipelined": _pipelined}[driver]
     g = rmat_graph(768, 8, seed=2)
-    b_sp, _ = buffcut_partition_vectorized(g, _stream_cfg("sparse"),
-                                           wave=8, chunk=8)
-    b_jx, st = buffcut_partition_vectorized(g, _stream_cfg("jax"),
-                                            wave=8, chunk=8)
+    b_sp, _ = run(g, _stream_cfg("sparse", k=k))
+    b_jx, st = run(g, _stream_cfg("jax", k=k))
     assert np.array_equal(b_sp, b_jx)
     assert st.n_batches >= 5
     assert st.ml_time_s > 0.0
@@ -421,47 +450,6 @@ def test_pack_ell_span_once_per_batch(mode, monkeypatch):
         packs = [i for i, name in enumerate(entered) if name == "vcycle.pack"]
         ells = [i for i, name in enumerate(entered) if name == "vcycle.pack_ell"]
         assert all(a < b for a, b in zip(packs, ells))
-
-
-def test_agg_autotune_identical_labels_and_converges():
-    """cfg.ml.agg_autotune explores both aggregation modes per (phase,
-    shape) then commits to the measured-fastest — exploration must never
-    change a label, and after warmup every key has a decision."""
-    g = rmat_graph(768, 8, seed=9)
-    k = 6
-    p = _params(g, k)
-    pinned = np.full(g.n, -1, dtype=np.int64)
-    ref = multilevel_partition(g, pinned, p, np.zeros(k),
-                               MultilevelConfig(engine="jax"))
-    mlj.reset_agg_tuner()
-    try:
-        cfg = MultilevelConfig(engine="jax", agg_autotune=True)
-        # warmup + timed samples for both candidates, then the decided mode
-        for _ in range(2 * (mlj._AggTuner.WARMUP + mlj._AggTuner.TIMED) + 1):
-            got = multilevel_partition(g, pinned, p, np.zeros(k), cfg)
-            assert np.array_equal(ref, got)  # exploration never leaks out
-        decisions = mlj.agg_decisions()
-        assert decisions, "tuner never converged to a decision"
-        assert set(decisions.values()) <= {"dense", "sort"}
-        for phase, n_pad, l_pad in decisions:
-            assert phase in ("cluster", "refine")
-            assert n_pad > 0 and l_pad > 0
-    finally:
-        mlj.reset_agg_tuner()
-
-
-def test_agg_autotune_off_by_default():
-    """MultilevelConfig defaults keep the tuner out of the loop (so jit
-    compilation counts stay deterministic for the cache-bound test)."""
-    assert MultilevelConfig().agg_autotune is False
-    mlj.reset_agg_tuner()
-    g = rmat_graph(256, 6, seed=2)
-    p = _params(g, 4)
-    pinned = np.full(g.n, -1, dtype=np.int64)
-    multilevel_partition(g, pinned, p, np.zeros(4),
-                         MultilevelConfig(engine="jax"))
-    assert mlj.agg_decisions() == {}
-    assert not mlj._TUNER._samples
 
 
 @pytest.mark.parametrize("n_pad", [1 << 10, 1 << 16, 1 << 17])

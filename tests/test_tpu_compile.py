@@ -74,21 +74,6 @@ def test_ell_histogram_compiles(one_chip, no_cache, rows, width, k, x64):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("x64", [False, True])
-@pytest.mark.parametrize("rows,width", [(ROWS // 2, 256), (ROWS, 8)])
-def test_fennel_gain_compiles(one_chip, no_cache, rows, width, x64):
-    with jax.enable_x64(x64):
-        compiled = jax.jit(
-            lambda b, w, loads, nw: ops.fennel_choose_batch(
-                b, w, loads, nw, alpha=0.5, gamma=1.5, cap=1024.0,
-                use_kernel=True, interpret=False)
-        ).lower(_spec(one_chip, (rows, width), jnp.int32),
-                _spec(one_chip, (rows, width), jnp.float32),
-                _spec(one_chip, (K,), jnp.float32),
-                _spec(one_chip, (rows,), jnp.float32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("mode,width,e_pad", [
     pytest.param("ell", 256, E_PAD, id="ell-256"),
     # mesh batches, 2^19 edge slots: a structured grid's (degree at most
