@@ -45,7 +45,7 @@ def _add_partition_parser(sub: "argparse._SubParsersAction") -> None:
     p.add_argument("--gamma", type=float, default=1.5)
     p.add_argument("--wave", type=int, default=1, help="vectorized: eviction wave size")
     p.add_argument("--chunk", type=int, default=1, help="vectorized: arrival chunk size")
-    p.add_argument("--queue-depth", type=int, default=4, help="pipelined: task queue bound")
+    p.add_argument("--queue-depth", type=int, default=4, help="pipelined: batch tasks queued for the partition worker (hubs ride free)")
     p.add_argument("--read-ahead", type=int, default=64, help="pipelined: read-ahead records")
     p.add_argument("--prefetch-batches", type=int, default=2,
                    help="stream prefetcher depth in batches (0 disables the "

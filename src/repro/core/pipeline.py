@@ -30,7 +30,17 @@ commit equal the serial driver's state and the emitted labels are
 bit-identical to `_buffcut_partition` for every `prefetch_batches` —
 pinned by tests/test_stream_conformance.py::test_prefetch_sweep_bit_identical.
 
-Shutdown is hardened (DESIGN.md §11): every queue put/get is bounded and
+T3 has precedence over T2 for the host.  T2's loop is Python under the
+interpreter lock; T3's batch model, commit and hub placement are chains of
+numpy calls, each of which drops and retakes that lock, so beside a running
+T2 every retake waits out the switch interval.  T3 closes a gate for its
+host work and opens it for the V-cycle call and while it waits for a task;
+T2 tests the gate once per record and, while it is closed, stands aside in
+a wait that does not hold the lock (span `buffcut.t2.yield`).  The task
+queue's `queue_depth` counts batch tasks only: hubs ride free (up to a
+batch's worth of them), so T2 can queue a batch ahead during the call.
+
+Shutdown is hardened (DESIGN.md §11): every blocking wait is bounded and
 watches a shared stop event, worker exceptions are captured and re-raised
 on the main thread, and a ``finally`` block poison-pills and joins the
 worker *and* the prefetch pump with a timeout on *every* exit path.
@@ -78,12 +88,17 @@ class PipelineConfig:
 
     `prefetch_batches` is the T1 read-ahead depth in δ-batches: 0 parses
     inline (serial), 1 is classic double buffering, more deepens the
-    window.  Like `queue_depth`, it changes throughput and staging
+    window.  `queue_depth` bounds the batch tasks queued for T3; hub
+    tasks ride free of it, so hubs between two batches never stop T2
+    from queuing a batch ahead (at most `batch_size` hubs wait in the
+    queue beyond its batch slots, which bounds a stream that is mostly
+    hubs).  Like
+    `queue_depth`, `prefetch_batches` changes throughput and staging
     residency, never labels.  `read_ahead` predates the block prefetcher
     and is kept for config compatibility; the prefetcher supersedes it.
     """
 
-    queue_depth: int = 4        # T2 -> T3 task queue bound
+    queue_depth: int = 4        # batch tasks queued for T3 (hubs ride free)
     read_ahead: int = 64        # legacy T1 record-queue bound (superseded)
     prefetch_batches: int = 2   # T1 read-ahead depth, in δ-batch blocks
 
@@ -155,11 +170,22 @@ def _buffcut_partition_pipelined(
     pq = BucketPQ(spec.s_max, cfg.disc_factor)
     block = np.full(n, -1, dtype=np.int64)
     loads = np.zeros(cfg.k, dtype=np.float64)
-    # committed-loads view is owned by the partition worker; the PQ handler
-    # reads a snapshot for hub assignment (slight staleness == paper's note
-    # that the parallel schedule can differ from the sequential one).
+    # block, loads and stats are T3's: hubs are placed in T3 too, so T2
+    # reads none of them and takes no snapshot (a checkpoint reads them
+    # after quiesce()).  T2 owns the rescore state, the buffer and the
+    # adjacency cache.
     lock = threading.Lock()
-    task_q: queue.Queue = queue.Queue(maxsize=pipe.queue_depth)
+    # the T2 -> T3 handoff (DESIGN.md §11): tasks in enqueue order; `slots`
+    # bounds the batch tasks queued to queue_depth, hubs and the poison pill
+    # take none (the queue's own bound only caps the hubs waiting behind a
+    # V-cycle at a batch's worth of records).  `gate` is open (set) unless
+    # T3 runs host work: T2 stands aside between records while it is
+    # closed, so T3's numpy chain does not wait on the interpreter lock
+    # behind T2's per-record Python.
+    task_q: queue.Queue = queue.Queue(maxsize=pipe.queue_depth + blk)
+    slots = threading.Semaphore(pipe.queue_depth)
+    gate = threading.Event()
+    gate.set()
     stats = StreamStats()
     batch: list[int] = []
     # queue/prefetch knobs change throughput, never labels (tasks commit in
@@ -246,8 +272,14 @@ def _buffcut_partition_pipelined(
     stage_s = stats.stage_s
 
     def next_task():
-        """T3's next queue item; None once stopped or poison-pilled."""
+        """T3's next queue item; None once stopped or poison-pilled.  A task
+        already queued is taken with the gate as it is, so T3 keeps the host
+        from one task to the next; the gate opens only when T3 must wait."""
         with span("buffcut.t3.task_wait", stage_s):
+            try:
+                return task_q.get_nowait()
+            except queue.Empty:
+                gate.set()
             while True:
                 try:
                     return task_q.get(timeout=_POLL_S)
@@ -261,9 +293,11 @@ def _buffcut_partition_pipelined(
                 item = next_task()
                 if item is None:
                     return
+                gate.clear()  # T3's host work: T2 stands aside
                 kind, payload = item
                 with lock:
                     if kind == "batch":
+                        slots.release()  # the batch is off the queue
                         bnodes, degs, nbr_c, w_c, node_w_b = payload
                         with span("buffcut.t3.batch_model", stage_s):
                             model = build_batch_model_from_adj(
@@ -272,11 +306,13 @@ def _buffcut_partition_pipelined(
                             note_peak(
                                 model.graph.indices.nbytes + model.graph.edge_w.nbytes
                             )
+                        gate.set()  # T2 does its records during the call
                         with span("buffcut.t3.vcycle", stage_s):
                             labels = multilevel_partition_resilient(
                                 model.graph, model.pinned_block, p, loads, cfg.ml,
                                 on_fallback=stats.note_engine_fallback,
                             )
+                        gate.clear()
                         stats.ml_time_s = stage_s["buffcut.t3.vcycle"]
                         with span("buffcut.t3.commit", stage_s):
                             lab_b = labels[: bnodes.shape[0]]
@@ -316,17 +352,20 @@ def _buffcut_partition_pipelined(
             stop.set()
             with done_cv:
                 done_cv.notify_all()
+        finally:
+            gate.set()  # no exit of T3 leaves T2 standing aside
 
     # daemon=True stays as a backstop, but the finally below always poison-
     # pills and joins, so normal operation never relies on it
-    worker = threading.Thread(target=partition_worker, daemon=True)
+    worker = threading.Thread(target=partition_worker, name="partition-worker",
+                              daemon=True)
     worker.start()
 
     def put_task(item) -> None:
         check_worker()
         try:
             task_q.put_nowait(item)
-        except queue.Full:
+        except queue.Full:  # a batch's worth of hubs waits for T3
             with span("buffcut.t2.put_wait", stage_s):
                 while True:
                     try:
@@ -336,6 +375,17 @@ def _buffcut_partition_pipelined(
                         check_worker()
         if item is not None:  # the poison pill is not a task
             counts["put"] += 1
+
+    gate_open = gate.is_set
+
+    def stand_aside() -> None:
+        """T2 waits, without the interpreter lock, until T3 opens the gate;
+        bounded waits that watch `stop`."""
+        with span("buffcut.t2.yield"):
+            while not gate.wait(_POLL_S):
+                if stop.is_set():
+                    break
+        check_worker()
 
     def flush_batch() -> None:
         if batch:
@@ -347,6 +397,10 @@ def _buffcut_partition_pipelined(
                 payload = (bnodes, degs, nbr_c, w_c, node_w_b)
                 with ilock:
                     inflight["task_bytes"] += _payload_bytes(payload)
+                if not slots.acquire(blocking=False):  # hubs take no slot
+                    with span("buffcut.t2.put_wait", stage_s):
+                        while not slots.acquire(timeout=_POLL_S):
+                            check_worker()
                 put_task(("batch", payload))
                 batch.clear()
 
@@ -391,6 +445,8 @@ def _buffcut_partition_pipelined(
             check_worker()
             with span("buffcut.t2.block", stage_s):
                 for ri in range(len(recs)):
+                    if not gate_open():
+                        stand_aside()
                     v, nbrs, nbr_w, node_w = recs[ri]
                     st.observe_scalar(v, nbrs, nbr_w, node_w)
                     if nbrs.size > d_max:
@@ -422,6 +478,8 @@ def _buffcut_partition_pipelined(
             note_peak()
         with span("buffcut.t2.block", stage_s):  # the buffer's final drain
             while len(pq) > 0:
+                if not gate_open():
+                    stand_aside()
                 u = extract()
                 member[u] = False
                 batch.append(u)

@@ -21,7 +21,9 @@ under the same name):
                          and extract, batch selection
   buffcut.t2.flush       T2 hands a batch to T3: adjacency slice, payload,
                          enqueue
-  buffcut.t2.put_wait    T2 blocked on a full task queue, behind T3
+  buffcut.t2.put_wait    T2 blocked behind T3 on a batch slot of the task
+                         queue (or on a queue holding a batch's worth
+                         of hubs)
   buffcut.t3.task_wait   T3 waiting for its next task, starved by T1 or T2
   buffcut.t3.batch_model T3 builds the batch model
   buffcut.t3.vcycle      T3's call of the multilevel engine on one batch
@@ -40,6 +42,11 @@ stage of its own:
   vcycle.pack_ell        inside vcycle.pack: the jax engine packs the
                          level-0 ELL tiles and copies them up, in a batch
                          whose level 0 aggregates through them
+  buffcut.t2.yield       inside buffcut.t2.block: T2 stands aside at the
+                         pipelined driver's gate while T3 runs host work
+                         (batch model, commit, hubs); entered only when T2
+                         blocks there, so its count is how often T2 stood
+                         aside and its time what the gate cost T2
 """
 from __future__ import annotations
 
